@@ -87,7 +87,7 @@ def _quantize_expert_slice(params, cfg, rank_mask: np.ndarray, ep: int):
     def qmap(path_w):
         def f(w):
             # w [nb, E, a, b] stacked expert weights: quantize along axis -2
-            wq = quant.fp4_sim(w.swapaxes(-1, -2)).swapaxes(-1, -2)
+            wq = quant.fp4_sim(w, axis=-2)
             m = sel.reshape((1, e) + (1,) * (w.ndim - 2))
             return jnp.where(m, wq, w)
         return f

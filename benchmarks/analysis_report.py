@@ -136,7 +136,8 @@ def run_census(tamper: bool) -> dict:
     from repro.configs import ReaLBConfig, get_config, reduced
     from repro.core import ep_moe
     from repro.launch.hlo_analysis import collective_census
-    from repro.models.common import shard_map, use_mesh
+    from repro.launch.mesh import make_mesh
+    from repro.models.common import use_mesh
     from repro.obs.ledger import FlopByteLedger
 
     cfg = reduced(get_config("olmoe-1b-7b"))
@@ -151,7 +152,7 @@ def run_census(tamper: bool) -> dict:
     mod = jax.random.bernoulli(ks[5], 0.6, (4, 16))
     rcfg = ReaLBConfig(gate_gamma=10 ** 9)
     L = 3
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     P = jax.sharding.PartitionSpec
 
     def fwd(p, x, m):
@@ -160,9 +161,9 @@ def run_census(tamper: bool) -> dict:
             y, m_n, aux = ep_moe.ep_moe_forward(p, x_c, cfg, rcfg, m_c,
                                                 mod, mode="dispatch")
             if tamper:      # one extra collective per layer
-                extra = shard_map(lambda a: jax.lax.psum(a, "model"),
-                                  mesh=mesh, in_specs=P(), out_specs=P(),
-                                  check_rep=False)(aux["drop_frac"])
+                extra = jax.shard_map(lambda a: jax.lax.psum(a, "model"),
+                                      mesh=mesh, in_specs=P(), out_specs=P(),
+                                      check_vma=False)(aux["drop_frac"])
                 y = y + extra * 0.0
             return (y, m_n), aux
         return jax.lax.scan(step, (x, m), None, length=L)

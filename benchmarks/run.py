@@ -29,17 +29,15 @@ def _kernel_microbench():
 
     from benchmarks import costmodel as cm
     from repro.core import quant
-    from repro.kernels import ref
 
     rows = []
     n, k, m = 1408, 2048, 4096
-    w = jax.random.normal(jax.random.PRNGKey(0), (n, k), jnp.float32) * 0.05
+    w = jax.random.normal(jax.random.PRNGKey(0), (k, n), jnp.float32) * 0.05
     x = jax.random.normal(jax.random.PRNGKey(1), (m, k), jnp.float32)
     qt = quant.quantize_fp4(w)
 
     f_q = jax.jit(lambda w: quant.quantize_fp4(w))
-    f_mm = jax.jit(lambda x: ref.fp4_matmul_ref(x, qt.packed, qt.scales,
-                                                qt.global_scale, a4=True))
+    f_mm = jax.jit(lambda x: quant.matmul_w4a4(x, qt))
     for name, f, arg, flops, bytes_ in (
             ("quantize_fp4", f_q, w, 0, n * k * 2.53),
             ("fp4_matmul_w4a4", f_mm, x, 2 * m * n * k,

@@ -654,7 +654,10 @@ def print_comparison(results: Dict[str, Dict]) -> None:
 def main(argv=None) -> int:
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
+
     args = parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.preset == "tiny":
         cfg = reduced(cfg)

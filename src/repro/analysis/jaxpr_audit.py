@@ -33,7 +33,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 #: primitive-name fragments that mean a host round trip
 _CALLBACK_RE = re.compile(r"callback")

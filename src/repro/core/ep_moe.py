@@ -92,7 +92,7 @@ from repro.core import quant
 from repro.core.policy import realb_policy
 from repro.kernels import nvfp4
 from repro.kernels import ops as kops
-from repro.models.common import P, current_mesh, resolve_spec, shard_map
+from repro.models.common import P, current_mesh, resolve_spec
 
 Params = Dict[str, jax.Array]
 F32 = jnp.float32
@@ -335,31 +335,29 @@ def _grouped_ffn(xs, gs, w_gate, w_up, w_down, act):
     return _rdot(h, w_down.astype(xs.dtype), gs)
 
 
-def _dq_t(q: quant.QTensor, dtype) -> jax.Array:
-    """Dequantize a [G,N,K]-layout QTensor to [G,K,N] for ragged_dot."""
-    return quant.dequantize_fp4(q, F32).swapaxes(-1, -2).astype(dtype)
-
-
 def _grouped_ffn_fp4(xs, gs, wq: Dict[str, quant.QTensor],
                      rcfg: ReaLBConfig, act):
     """NVFP4 W4A4 grouped FFN, backend-switched at trace time.
 
     With ``kernels.ops.ffn_backend() != "jnp"`` this is the fused Pallas
-    grouped kernel (native on TPU, interpret-mode on CPU): packed weights
-    stream HBM→VMEM at 4.25 bits/weight and the intermediate ``h`` never
-    round-trips HBM.  The jnp fallback below is the numerics oracle the
-    kernel is pinned against — same dynamic per-group-16 activation
-    fake-quant (``nvfp4.fake_quant_a4``), dequantize + ``ragged_dot``.
+    grouped kernel (native on TPU, interpret-mode in tests): packed weights
+    stream HBM→VMEM and the intermediate ``h`` never round-trips HBM.  The
+    jnp fallback below is the numerics oracle the kernel is pinned against
+    — same dynamic per-group-16 activation fake-quant
+    (``nvfp4.fake_quant_a4``), dequantize + ``ragged_dot``.
     """
     if kops.ffn_backend() != "jnp":
         return kops.grouped_fp4_ffn(xs, gs, wq, group=rcfg.group_size,
                                     act=act)
+    dq = {n: quant.dequantize_fp4(q, xs.dtype) for n, q in wq.items()}
     xq = nvfp4.fake_quant_a4(xs, rcfg.group_size).astype(xs.dtype)
-    g = _rdot(xq, _dq_t(wq["w_gate"], xs.dtype), gs)
-    u = _rdot(xq, _dq_t(wq["w_up"], xs.dtype), gs)
-    h = act(g.astype(F32)).astype(xs.dtype) * u
-    hq = nvfp4.fake_quant_a4(h, rcfg.group_size).astype(xs.dtype)
-    return _rdot(hq, _dq_t(wq["w_down"], xs.dtype), gs)
+    g = jax.lax.ragged_dot(xq, dq["w_gate"], gs, preferred_element_type=F32)
+    u = jax.lax.ragged_dot(xq, dq["w_up"], gs, preferred_element_type=F32)
+    # h stays f32 up to its a4: XLA may skip a bf16 rounding of h (excess
+    # precision) where Mosaic keeps it, and the piecewise-constant a4
+    # would turn that into whole-level jumps between kernel and oracle
+    hq = nvfp4.fake_quant_a4(act(g) * u, rcfg.group_size).astype(xs.dtype)
+    return _rdot(hq, dq["w_down"], gs)
 
 
 def _quantize_experts(w: Dict[str, jax.Array], use_fp4: jax.Array,
@@ -377,17 +375,16 @@ def _quantize_experts(w: Dict[str, jax.Array], use_fp4: jax.Array,
     def do_quant(ws):
         out = {}
         use_kernel = kops.ffn_backend() != "jnp"
-        for name, wt in ws.items():
-            wt_t = wt.swapaxes(-1, -2)  # [G, N, K]: quantize along K
+        for name, wt in ws.items():      # [G, K, N]: quantize along K
             if overlap_token is not None:
-                wt_t = wt_t + overlap_token.astype(wt_t.dtype)
+                wt = wt + overlap_token.astype(wt.dtype)
             if use_kernel:
                 # Pallas quantize kernel — bitwise-identical to the jnp
-                # recipe, but streams the slab once at 4.25 bits/wt out.
+                # recipe, but streams the slab once.
                 out[name] = kops.quantize_experts_fp4(
-                    wt_t, group=rcfg.group_size)
+                    wt, group=rcfg.group_size)
             else:
-                out[name] = quant.quantize_fp4(wt_t, rcfg.group_size)
+                out[name] = quant.quantize_fp4(wt, rcfg.group_size)
         return out
 
     def no_quant(ws):
@@ -395,11 +392,10 @@ def _quantize_experts(w: Dict[str, jax.Array], use_fp4: jax.Array,
         # type matches the quantizing branch under shard_map
         out = {}
         for name, wt in ws.items():
-            wt_t = wt.swapaxes(-1, -2)
             out[name] = quant.QTensor(
-                (wt_t[..., ::2] * 0).astype(jnp.uint8),
-                (wt_t[..., ::rcfg.group_size] * 0).astype(F32),
-                (wt_t.reshape(-1)[0] * 0 + 1).astype(F32))
+                (wt[..., ::2, :] * 0).astype(jnp.uint8),
+                (wt[..., ::rcfg.group_size, :] * 0).astype(F32),
+                (wt.reshape(-1)[0] * 0 + 1).astype(F32))
         return out
 
     return jax.lax.cond(use_fp4, do_quant, no_quant, w)
@@ -644,11 +640,14 @@ def _moe_broadcast(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
             # decode and prefill FP4 numerics agree across backends
             x_, _, wq_ = o
             xq = nvfp4.fake_quant_a4(x_, rcfg.group_size).astype(x_.dtype)
-            wd = {n: _dq_t(q, x_.dtype) for n, q in wq_.items()}
-            g = jnp.einsum("td,edf->etf", xq, wd["w_gate"])
-            u = jnp.einsum("td,edf->etf", xq, wd["w_up"])
-            h = act(g.astype(F32)).astype(x_.dtype) * u
-            hq = nvfp4.fake_quant_a4(h, rcfg.group_size).astype(x_.dtype)
+            wd = {n: quant.dequantize_fp4(q, x_.dtype)
+                  for n, q in wq_.items()}
+            g = jnp.einsum("td,edf->etf", xq, wd["w_gate"],
+                           preferred_element_type=F32)
+            u = jnp.einsum("td,edf->etf", xq, wd["w_up"],
+                           preferred_element_type=F32)
+            hq = nvfp4.fake_quant_a4(act(g) * u,
+                                     rcfg.group_size).astype(x_.dtype)
             return jnp.einsum("etf,efd->etd", hq, wd["w_down"])
 
         y_e = jax.lax.cond(use_fp4_me, fp4_branch, bf16_branch,
@@ -808,15 +807,15 @@ def ep_moe_forward(p: Params, x: jax.Array, cfg: ModelConfig,
     if sched is not None:                # replicated [E, Q] split schedule
         table_args += (sched,)
         table_specs += (t2_spec,)
-    # check_rep=False: pallas_call (the FP4 quantize / grouped-FFN
+    # check_vma=False: pallas_call (the FP4 quantize / grouped-FFN
     # kernels) has no replication rule; the out_specs above already state
     # the sharding we require, so only the static replication lint is lost
-    y, m_new, aux_s, stats, estats, sstats = shard_map(
+    y, m_new, aux_s, stats, estats, sstats = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(x_spec, mod_spec, mod_spec, m_spec, r_spec, wg_spec,
                   wg_spec, wd_spec) + table_specs,
         out_specs=(x_spec, m_spec, aux_spec, stats_spec, stats_spec,
-                   stats_spec), check_rep=False,
+                   stats_spec), check_vma=False,
     )(x, modality, valid, m_state, p["router"], p["w_gate"], p["w_up"],
       p["w_down"], *table_args)
 

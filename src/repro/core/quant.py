@@ -7,11 +7,14 @@ local scales fit E4M3 range.  These functions are the numerical oracle for
 the Pallas kernels in ``repro/kernels`` and the accuracy-measurement path
 of the benchmarks (the simulated dequantized values are bit-identical to
 what an NVFP4 GEMM consumes).
+
+Weights are stored as ``[..., K, N]`` (contraction second-to-last, output
+last) and grouped along K; the packed layout is defined once, in
+:mod:`repro.kernels.nvfp4`, for this oracle and the kernels alike.
 """
 from __future__ import annotations
 
-from functools import partial
-from typing import NamedTuple, Tuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -35,31 +38,16 @@ fp4_decode = nvfp4.decode_level
 e4m3_round = nvfp4.e4m3_round
 
 
-def pack_u4(codes: jax.Array) -> jax.Array:
-    """Pack uint8 4-bit codes pairwise along the last dim -> uint8 [... , K/2]."""
-    lo = codes[..., 0::2].astype(jnp.uint8)
-    hi = codes[..., 1::2].astype(jnp.uint8)
-    return (lo | (hi << 4)).astype(jnp.uint8)
-
-
-def unpack_u4(packed: jax.Array) -> jax.Array:
-    """Inverse of :func:`pack_u4` -> uint8 [..., K]."""
-    lo = (packed & 0x0F).astype(jnp.uint8)
-    hi = ((packed >> 4) & 0x0F).astype(jnp.uint8)
-    return jnp.stack([lo, hi], axis=-1).reshape(*packed.shape[:-1],
-                                                packed.shape[-1] * 2)
-
-
 class QTensor(NamedTuple):
-    """Group-quantized NVFP4 tensor (packed along the last axis)."""
+    """Group-quantized NVFP4 weight, grouped and packed along K (axis -2)."""
 
-    packed: jax.Array        # uint8 [..., K/2]
-    scales: jax.Array        # f32 (e4m3-valued) [..., K/GROUP]
+    packed: jax.Array        # uint8 [..., K/2, N]
+    scales: jax.Array        # f32 (e4m3-valued) [..., K/GROUP, N]
     global_scale: jax.Array  # f32 scalar
 
     @property
     def k(self) -> int:
-        return self.packed.shape[-1] * 2
+        return self.packed.shape[-2] * 2
 
 
 def global_scale_for(w: jax.Array) -> jax.Array:
@@ -71,38 +59,32 @@ def global_scale_for(w: jax.Array) -> jax.Array:
 
 def quantize_fp4(w: jax.Array, group: int = GROUP,
                  global_scale: jax.Array | None = None) -> QTensor:
-    """NVFP4 group quantization along the last axis (must divide by group)."""
-    *lead, k = w.shape
-    assert k % group == 0, (k, group)
-    wf = w.astype(jnp.float32).reshape(*lead, k // group, group)
-    amax = jnp.max(jnp.abs(wf), axis=-1)                      # [..., K/g]
+    """NVFP4 group quantization of ``w [..., K, N]`` along K
+    (``K % (2·group) == 0``)."""
+    k = w.shape[-2]
+    assert k % (2 * group) == 0, (w.shape, group)
     gscale = global_scale_for(w) if global_scale is None \
         else jnp.asarray(global_scale, jnp.float32)
-    # multiply by the f32 reciprocal (not /6.0): keeps the expression
-    # bit-identical between the jitted oracle and the Pallas kernel (XLA
-    # rewrites constant divisions to reciprocal multiplies)
-    s_local = e4m3_round(amax * INV_FP4_MAX / gscale)
-    s_local = jnp.maximum(s_local, 2.0 ** -9)                 # avoid /0
-    codes = fp4_code(wf / (s_local * gscale)[..., None])
-    packed = pack_u4(codes.reshape(*lead, k))
-    return QTensor(packed, s_local, gscale.astype(jnp.float32))
+    packed, scales = nvfp4.quantize_rows(w, gscale, group)
+    return QTensor(packed, scales, gscale.astype(jnp.float32))
 
 
 def dequantize_fp4(q: QTensor, dtype=jnp.float32) -> jax.Array:
-    vals = fp4_decode(unpack_u4(q.packed))                    # [..., K]
-    *lead, k = vals.shape
-    g = k // q.scales.shape[-1]
-    vals = vals.reshape(*lead, k // g, g) * q.scales[..., None] * q.global_scale
-    return vals.reshape(*lead, k).astype(dtype)
+    """``QTensor`` -> ``[..., K, N]`` in ``dtype``."""
+    return nvfp4.dequant_rows(q.packed, q.scales,
+                              q.global_scale).astype(dtype)
 
 
-def fp4_sim(x: jax.Array, group: int = GROUP) -> jax.Array:
-    """Fake-quantize (quantize+dequantize) along the last axis, same dtype.
+def fp4_sim(x: jax.Array, group: int = GROUP, axis: int = -1) -> jax.Array:
+    """Fake-quantize (quantize+dequantize) along ``axis`` (-1 or -2), same
+    dtype.
 
     Gradient-transparent (straight-through) so it can sit in train graphs.
     """
-    q = quantize_fp4(jax.lax.stop_gradient(x), group)
+    xs = x.swapaxes(-1, -2) if axis == -1 else x
+    q = quantize_fp4(jax.lax.stop_gradient(xs), group)
     dq = dequantize_fp4(q, jnp.float32)
+    dq = dq.swapaxes(-1, -2) if axis == -1 else dq
     xf = x.astype(jnp.float32)
     return (xf + jax.lax.stop_gradient(dq - xf)).astype(x.dtype)
 
@@ -118,13 +100,13 @@ def quant_error(w: jax.Array, group: int = GROUP) -> jax.Array:
 # quantized matmul references (the numerics the kernels must match)
 # --------------------------------------------------------------------------
 def matmul_w4a16(x: jax.Array, qw: QTensor) -> jax.Array:
-    """x [M,K] @ dequant(qw) [K,N] with qw quantized along K (stored [N,K])."""
-    w = dequantize_fp4(qw, jnp.float32)                       # [N,K]
-    return (x.astype(jnp.float32) @ w.T).astype(x.dtype)
+    """x [M,K] @ dequant(qw) [K,N]."""
+    w = dequantize_fp4(qw, jnp.float32)                       # [K,N]
+    return (x.astype(jnp.float32) @ w).astype(x.dtype)
 
 
 def matmul_w4a4(x: jax.Array, qw: QTensor, group: int = GROUP) -> jax.Array:
     """NVFP4 W4A4 GEMM simulation: both operands fake-quantized per group-K."""
     xq = fp4_sim(x.astype(jnp.float32), group)
     w = dequantize_fp4(qw, jnp.float32)
-    return (xq @ w.T).astype(x.dtype)
+    return (xq @ w).astype(x.dtype)

@@ -1,2 +1,3 @@
 """Pallas TPU kernels for the paper's compute hot-spots (NVFP4 quantize +
-W4A4 GEMM) with jnp oracles in ref.py and jit wrappers in ops.py."""
+grouped FP4 expert FFN), their shared numerics in nvfp4.py and the serving
+entry points in ops.py."""

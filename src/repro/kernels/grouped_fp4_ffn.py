@@ -8,13 +8,20 @@ dequantize → ``ragged_dot`` × 3:
   counts ``gs [G]``; the prefix-sum offsets and a skip map over empty
   slots are **scalar-prefetched** so BlockSpec index maps can steer weight
   DMA before the grid step runs;
-* packed E2M1 codes + E4M3-valued group-16 scales stream HBM→VMEM at
-  4.25 bits/weight and are dequantized in-register (compare-select decode
-  from ``repro.kernels.nvfp4`` — no gathers);
-* activation fake-quant (a4), the SwiGLU ``act(x·Wg) ⊙ (x·Wu)`` elementwise
-  stage, and the down projection all happen on the same VMEM-resident
-  tiles, so the intermediate ``h [M, d_ff]`` never round-trips HBM and the
-  BF16 dequantized weights never exist outside a register tile.
+* packed E2M1 codes + E4M3-valued group-16 scales stream HBM→VMEM and are
+  dequantized on VMEM tiles by ``nvfp4.dequant_rows`` — the oracle's own
+  function (compare-select decode, no gathers);
+* the activation fake-quant (a4) of ``xs`` is row-local, so it runs once
+  over all rows before the kernel; the a4 of ``h``, the SwiGLU
+  ``act(x·Wg) ⊙ (x·Wu)`` stage and the down projection all happen on the
+  same VMEM-resident tiles, so ``h [M, d_ff]`` never round-trips HBM and
+  the dequantized weights never exist outside a VMEM tile.
+
+Weights use the ``[G, K/2, N]`` / ``[G, K/16, N]`` layout of
+:mod:`repro.kernels.nvfp4` (contraction axis second-to-last), so every
+tile is a plain ``[rows, lanes]`` operand of an ``x @ W`` matmul and no
+step splits the lane axis; the ``h`` a4 groups run along lanes, so the
+kernel transposes the ``[bm, bf]`` tile and groups along sublanes.
 
 Grid ``(M/bm, G, F/bf)``: token-block outermost so the f32 output
 accumulator (VMEM scratch, zeroed at ``g==f==0``, flushed at the last
@@ -22,13 +29,13 @@ accumulator (VMEM scratch, zeroed at ``g==f==0``, flushed at the last
 tokens (or no row overlap with the current token block) skips all compute
 via ``pl.when``; its weight-block index is remapped to the last non-empty
 slot at or before it (``gmap``), so consecutive grid steps see the same
-block index and Pallas elides the DMA — empty slots cost neither flops nor
-HBM traffic.
+block index and Pallas elides the DMA.
 
-VMEM per step (full-model shapes D=2048, F=1408 → bf=128, bm=128):
-x 512 KiB + acc 1 MiB + gate/up packed 2·128 KiB + down packed 128 KiB +
-scales ~48 KiB ≈ 1.9 MiB, comfortably inside ~16 MiB with double
-buffering.  On CPU the same kernel runs under ``interpret=True`` for
+``bf`` is 256 or 128 — whichever first divides d_ff (all of d_ff when
+none does); at Moonlight widths (D=2048, d_ff=1408) it is 128.  VMEM per
+step there: x 512 KiB + acc 1 MiB + packed gate/up/down 3·128 KiB +
+scales 3·64 KiB, double-buffered, plus two dequantized f32 weight tiles
+of 1 MiB each.  On CPU the same kernel runs under ``interpret=True`` for
 oracle parity (see ``repro.kernels.ops.ffn_backend``).
 """
 from __future__ import annotations
@@ -40,21 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.nvfp4 import GROUP, decode_level, fake_quant_a4
-
-
-def _dequant_tile(packed, scales, gscale, group, dtype):
-    """[R, C/2] u8 + [R, C/group] scales -> [R, C] weight tile in ``dtype``.
-
-    Mirrors the jnp oracle's multiply order exactly:
-    ``(levels * local_scale) * global_scale`` (see quant.dequantize_fp4).
-    """
-    r, c2 = packed.shape
-    lo = decode_level(packed & 0x0F)
-    hi = decode_level((packed >> 4) & 0x0F)
-    vals = jnp.stack([lo, hi], axis=-1).reshape(r, c2 * 2)
-    w = (vals.reshape(r, c2 * 2 // group, group) * scales[..., None]) * gscale
-    return w.reshape(r, c2 * 2).astype(dtype)
+from repro.kernels.nvfp4 import GROUP, dequant_rows, fake_quant_a4
 
 
 def _ffn_kernel(offs_ref, gmap_ref, x_ref, gsc_ref,
@@ -75,43 +68,38 @@ def _ffn_kernel(offs_ref, gmap_ref, x_ref, gsc_ref,
     # Skip empty slots and token blocks with no rows in this slot.
     @pl.when((r1 > r0) & (row0 < r1) & (row0 + block_m > r0))
     def _compute():
-        dtype = x_ref.dtype
-        x = x_ref[...].astype(jnp.float32)                   # [bm, D]
+        xq = x_ref[...]                                       # [bm, D]
+        dtype = xq.dtype
         rows = row0 + jax.lax.broadcasted_iota(
             jnp.int32, (block_m, 1), 0)
         mask = (rows >= r0) & (rows < r1)
-        x = jnp.where(mask, x, 0.0)
-        # oracle: xq = fake_quant_a4(xs) once over all rows — row-local, so
-        # recomputing per (block, slot) with masked rows is identical.
-        xq = fake_quant_a4(x, group).astype(dtype)
+        xq = jnp.where(mask, xq, jnp.zeros_like(xq))
 
-        gsc = gsc_ref[...]                                    # [1, 3]
-        wg = _dequant_tile(wgp_ref[0], wgs_ref[0], gsc[0, 0], group, dtype)
-        wu = _dequant_tile(wup_ref[0], wus_ref[0], gsc[0, 1], group, dtype)
+        wg = dequant_rows(wgp_ref[0], wgs_ref[0],             # [D, bf]
+                          gsc_ref[0, 0]).astype(dtype)
+        wu = dequant_rows(wup_ref[0], wus_ref[0],
+                          gsc_ref[0, 1]).astype(dtype)
+        # gate, up and h stay f32 up to the a4 of h, as in the oracle
+        gate = jnp.dot(xq, wg, preferred_element_type=jnp.float32)
+        up = jnp.dot(xq, wu, preferred_element_type=jnp.float32)
+        h = act(gate) * up                                    # [bm, bf]
+        # a4 groups run along d_ff: transpose so they split sublanes
+        hq = fake_quant_a4(h.T, group, axis=-2).T.astype(dtype)
 
-        gate = jax.lax.dot_general(                           # [bm, bf]
-            xq, wg, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dtype)
-        up = jax.lax.dot_general(
-            xq, wu, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dtype)
-        h = (act(gate.astype(jnp.float32)).astype(dtype) * up)
-        hq = fake_quant_a4(h, group).astype(dtype)
-
-        wd = _dequant_tile(wdp_ref[0], wds_ref[0], gsc[0, 2], group, dtype)
-        acc_ref[...] += jax.lax.dot_general(                  # [bm, D]
-            hq, wd, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        wd = dequant_rows(wdp_ref[0], wds_ref[0],             # [bf, D]
+                          gsc_ref[0, 2]).astype(dtype)
+        acc_ref[...] += jnp.dot(hq, wd, preferred_element_type=jnp.float32)
 
     @pl.when((g == n_g - 1) & (f == n_f - 1))
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _pick_block_f(f: int, group: int) -> int:
-    """Largest divisor of d_ff ≤ 512 that keeps group-16 scale tiles whole."""
-    for cand in (512, 256, 128, 64, 32, 16):
-        if f % cand == 0 and cand % group == 0:
+def _pick_block_f(f: int) -> int:
+    """Largest lane-aligned divisor of d_ff from (256, 128), else
+    all of d_ff (a full-dimension block is always legal)."""
+    for cand in (256, 128):
+        if f % cand == 0:
             return cand
     return f
 
@@ -130,21 +118,24 @@ def grouped_fp4_ffn_kernel(xs: jax.Array, gs: jax.Array,
     """Fused grouped FP4 SwiGLU FFN: ``xs [M, D]`` sorted by slot → ``[M, D]``.
 
     ``gs [G]`` int32 token counts per slot (``sum(gs) == M``);
-    gate/up quantized along D (``packed [G, F, D/2]``, ``scales
-    [G, F, D/group]``), down along F (``[G, D, F/2]``, ``[G, D, F/group]``);
+    gate/up quantized along D (``packed [G, D/2, F]``, ``scales
+    [G, D/group, F]``), down along F (``[G, F/2, D]``, ``[G, F/group, D]``);
     ``global_scales [3]`` f32 per-tensor scales (gate, up, down).
-    Rows are padded to ``block_m`` internally — callers pass real ``M``.
+    Rows are padded to a multiple of ``block_m`` internally — callers pass
+    real ``M``.
     """
     m, d = xs.shape
     n_groups = gs.shape[0]
-    f = gate_packed.shape[1]
+    f = gate_packed.shape[-1]
     assert d % (2 * group) == 0 and f % (2 * group) == 0, (d, f)
 
-    block_m = min(block_m, max(8, m))
+    out_dtype = out_dtype or xs.dtype
+    # a4 of the input is row-local: once over all rows, as the oracle does
+    xs = fake_quant_a4(xs, group).astype(xs.dtype)
     mp = -(-m // block_m) * block_m
     if mp != m:
         xs = jnp.pad(xs, ((0, mp - m), (0, 0)))
-    block_f = _pick_block_f(f, group)
+    block_f = _pick_block_f(f)
     grid = (mp // block_m, n_groups, f // block_f)
 
     gs = gs.astype(jnp.int32)
@@ -159,7 +150,6 @@ def grouped_fp4_ffn_kernel(xs: jax.Array, gs: jax.Array,
 
     kernel = functools.partial(_ffn_kernel, group=group, act=act,
                                n_g=n_groups, n_f=grid[2], block_m=block_m)
-    out_dtype = out_dtype or xs.dtype
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -168,18 +158,18 @@ def grouped_fp4_ffn_kernel(xs: jax.Array, gs: jax.Array,
             in_specs=[
                 pl.BlockSpec((block_m, d), lambda i, g, f, offs, gmap: (i, 0)),
                 pl.BlockSpec((1, 3), lambda i, g, f, offs, gmap: (0, 0)),
-                pl.BlockSpec((1, block_f, d // 2),
-                             lambda i, g, f, offs, gmap: (gmap[g], f, 0)),
-                pl.BlockSpec((1, block_f, d // group),
-                             lambda i, g, f, offs, gmap: (gmap[g], f, 0)),
-                pl.BlockSpec((1, block_f, d // 2),
-                             lambda i, g, f, offs, gmap: (gmap[g], f, 0)),
-                pl.BlockSpec((1, block_f, d // group),
-                             lambda i, g, f, offs, gmap: (gmap[g], f, 0)),
-                pl.BlockSpec((1, d, block_f // 2),
+                pl.BlockSpec((1, d // 2, block_f),
                              lambda i, g, f, offs, gmap: (gmap[g], 0, f)),
-                pl.BlockSpec((1, d, block_f // group),
+                pl.BlockSpec((1, d // group, block_f),
                              lambda i, g, f, offs, gmap: (gmap[g], 0, f)),
+                pl.BlockSpec((1, d // 2, block_f),
+                             lambda i, g, f, offs, gmap: (gmap[g], 0, f)),
+                pl.BlockSpec((1, d // group, block_f),
+                             lambda i, g, f, offs, gmap: (gmap[g], 0, f)),
+                pl.BlockSpec((1, block_f // 2, d),
+                             lambda i, g, f, offs, gmap: (gmap[g], f, 0)),
+                pl.BlockSpec((1, block_f // group, d),
+                             lambda i, g, f, offs, gmap: (gmap[g], f, 0)),
             ],
             out_specs=pl.BlockSpec((block_m, d),
                                    lambda i, g, f, offs, gmap: (i, 0)),

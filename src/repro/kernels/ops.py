@@ -1,37 +1,25 @@
-"""Public jit'd wrappers for the Pallas kernels + the serving backend switch.
-
-On TPU backends the kernels compile natively; on CPU (this container) they
-execute in ``interpret=True`` mode, which runs the kernel body in Python —
-the correctness tests sweep shapes/dtypes against :mod:`repro.kernels.ref`.
+"""Serving entry points for the Pallas kernels + the FP4 FFN backend switch.
 
 The serving hot loop (``repro.core.ep_moe``) picks its FP4 expert-FFN
-implementation through :func:`ffn_backend`:
+implementation through :func:`ffn_backend`.  The platform decides:
 
-* ``"pallas"``    — fused grouped kernel, compiled natively (TPU default);
-* ``"interpret"`` — same kernel under the Pallas interpreter (CPU oracle
-  parity; slow, used by tests and the profiled CI bench arm);
-* ``"jnp"``       — the dequantize + ``ragged_dot`` jnp oracle (CPU
-  default: fast enough to serve, numerically the reference).
+* ``"pallas"``    — the Pallas kernels, compiled natively (on TPU);
+* ``"jnp"``       — the dequantize + ``ragged_dot`` jnp oracle (elsewhere:
+  fast enough to serve, numerically the reference).
 
-The choice is read at *trace* time: call :func:`set_ffn_backend` (or set
-``REPRO_FFN_BACKEND``) before building/jitting an engine; already-compiled
+Tests may pin a backend with :func:`set_ffn_backend`, including
+``"interpret"`` — the same kernels under the Pallas interpreter, for
+oracle parity on CPU.  The choice is read at *trace* time: already-compiled
 functions keep the backend they were traced with.
-
-All wrappers pad inputs to block multiples internally and slice the
-result, so real routed token counts (``ep·cap`` with cap rounded to 8,
-arbitrary d_ff) need no caller-side padding.
 """
 from __future__ import annotations
 
-import functools
-import os
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.quant import QTensor, global_scale_for
-from repro.kernels.fp4_matmul import fp4_matmul_kernel
 from repro.kernels.grouped_fp4_ffn import grouped_fp4_ffn_kernel
 from repro.kernels.quantize_fp4 import quantize_fp4_kernel
 
@@ -39,26 +27,16 @@ FFN_BACKENDS = ("pallas", "interpret", "jnp")
 _ffn_backend_override: Optional[str] = None
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-# --------------------------------------------------------------------------
-# serving backend switch
-# --------------------------------------------------------------------------
 def ffn_backend() -> str:
     """Resolve the FP4 expert-FFN backend for the serving hot loop."""
     if _ffn_backend_override is not None:
         return _ffn_backend_override
-    env = os.environ.get("REPRO_FFN_BACKEND", "").strip().lower()
-    if env in FFN_BACKENDS:
-        return env
     return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
 def set_ffn_backend(name: Optional[str]) -> str:
     """Override the backend ("pallas" | "interpret" | "jnp"); ``None`` or
-    ``"auto"`` restores env/default resolution.  Returns the active backend.
+    ``"auto"`` restores the platform default.  Returns the active backend.
     Takes effect for functions traced *after* the call."""
     global _ffn_backend_override
     if name is None or name == "auto":
@@ -78,106 +56,19 @@ def ffn_fused() -> bool:
     return ffn_backend() != "jnp"
 
 
-# --------------------------------------------------------------------------
-# padding helpers (satellite: no hard shape asserts at the call sites)
-# --------------------------------------------------------------------------
-def _pad_dim(x: jax.Array, axis: int, mult: int) -> jax.Array:
-    pad = (-x.shape[axis]) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
+def _interpret(interpret: bool | None) -> bool:
+    return (ffn_backend() != "pallas") if interpret is None else interpret
 
 
-def _fit_block(size: int, block: int, align: int) -> int:
-    """Largest usable block ≤ ``block`` that is a multiple of ``align``;
-    sizes below one block collapse to the (aligned-up) size itself."""
-    if size <= block:
-        return -(-size // align) * align
-    return max(align, (block // align) * align)
-
-
-def quantize_fp4(w: jax.Array, global_scale: jax.Array | None = None, *,
-                 group: int = 16, block_n: int = 256, block_k: int = 512,
-                 interpret: bool | None = None
-                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """NVFP4-quantize ``w [N,K]`` along K. Returns (packed, scales, gscale).
-
-    ``K`` must be a multiple of ``2·group`` (the storage format); ``N`` and
-    ``K`` are otherwise arbitrary — tiles are padded internally.
-    """
-    n, k = w.shape
-    assert k % (2 * group) == 0, (w.shape, group)
-    if global_scale is None:
-        global_scale = global_scale_for(w)
-    interpret = _interpret_default() if interpret is None else interpret
-    bn = _fit_block(n, block_n, 8)
-    bk = _fit_block(k, block_k, 2 * group)
-    wp = _pad_dim(_pad_dim(w, 0, bn), 1, bk)
-    packed, scales = quantize_fp4_kernel(
-        wp, global_scale, group=group, block_n=bn, block_k=bk,
-        interpret=interpret)
-    return (packed[:n, :k // 2], scales[:n, :k // group],
-            jnp.asarray(global_scale, jnp.float32))
-
-
-def fp4_matmul(x: jax.Array, packed: jax.Array, scales: jax.Array,
-               global_scale: jax.Array, *, group: int = 16,
-               a4: bool = False, out_dtype=jnp.float32,
-               block_m: int = 128, block_n: int = 256, block_k: int = 512,
-               interpret: bool | None = None) -> jax.Array:
-    """``x [M,K] @ W^T`` with W stored as packed NVFP4 ``[N,K/2]``.
-
-    Arbitrary M/N; K must be a multiple of ``2·group``.  Inputs are padded
-    to block multiples (zero rows/cols/groups contribute exact zeros) and
-    the result is sliced back to ``[M,N]``.
-    """
-    interpret = _interpret_default() if interpret is None else interpret
-    m, k = x.shape
-    n = packed.shape[0]
-    assert k % (2 * group) == 0, (x.shape, group)
-    bm = _fit_block(m, block_m, 8)
-    bn = _fit_block(n, block_n, 8)
-    bk = _fit_block(k, block_k, 2 * group)
-    xp = _pad_dim(_pad_dim(x, 0, bm), 1, bk)
-    pp = _pad_dim(_pad_dim(packed, 0, bn), 1, bk // 2)
-    sp = _pad_dim(_pad_dim(scales, 0, bn), 1, bk // group)
-    out = fp4_matmul_kernel(
-        xp, pp, sp, global_scale, group=group, a4=a4,
-        block_m=bm, block_n=bn, block_k=bk,
-        interpret=interpret, out_dtype=out_dtype)
-    return out[:m, :n]
-
-
-def fp4_linear(x: jax.Array, w: jax.Array, *, a4: bool = False,
-               group: int = 16, interpret: bool | None = None) -> jax.Array:
-    """Convenience: quantize-then-matmul (the full on-the-fly T + GEMM path).
-
-    x [M,K] bf16 @ w [K,N] bf16 → [M,N] f32 with NVFP4 weight (and
-    optionally activation) numerics.
-    """
-    packed, scales, gs = quantize_fp4(w.swapaxes(0, 1), group=group,
-                                      interpret=interpret)
-    return fp4_matmul(x, packed, scales, gs, group=group, a4=a4,
-                      interpret=interpret)
-
-
-# --------------------------------------------------------------------------
-# serving hot-loop entry points (grouped over the expert-slot dimension)
-# --------------------------------------------------------------------------
-def quantize_experts_fp4(wt: jax.Array, *, group: int = 16,
+def quantize_experts_fp4(w: jax.Array, *, group: int = 16,
                          interpret: bool | None = None) -> QTensor:
-    """Quantize a ``[G, N, K]`` expert weight stack along K via the Pallas
+    """Quantize a ``[G, K, N]`` expert weight stack along K via the Pallas
     kernel.  Bitwise-identical to ``quant.quantize_fp4`` (same global
     scale over the whole stack, same per-group recipe)."""
-    g, n, k = wt.shape
-    gscale = global_scale_for(wt)
-    interpret = (ffn_backend() != "pallas") if interpret is None else interpret
-    packed, scales = quantize_fp4(wt.reshape(g * n, k), gscale, group=group,
-                                  interpret=interpret)[:2]
-    return QTensor(packed.reshape(g, n, k // 2),
-                   scales.reshape(g, n, k // group), gscale)
+    gscale = global_scale_for(w)
+    packed, scales = quantize_fp4_kernel(w, gscale, group=group,
+                                         interpret=_interpret(interpret))
+    return QTensor(packed, scales, gscale)
 
 
 def grouped_fp4_ffn(xs: jax.Array, gs: jax.Array,
@@ -186,10 +77,9 @@ def grouped_fp4_ffn(xs: jax.Array, gs: jax.Array,
                     interpret: bool | None = None) -> jax.Array:
     """Fused grouped FP4 SwiGLU FFN over slot-sorted tokens (see
     ``repro.kernels.grouped_fp4_ffn``).  ``wq`` holds ``w_gate``/``w_up``
-    quantized along D and ``w_down`` quantized along d_ff, exactly as
-    produced by ``_quantize_experts`` in the hot loop."""
+    ``[G, D, F]`` and ``w_down`` ``[G, F, D]``, each quantized along its
+    contraction axis, exactly as ``_quantize_experts`` produces them."""
     qg, qu, qd = wq["w_gate"], wq["w_up"], wq["w_down"]
-    interpret = (ffn_backend() != "pallas") if interpret is None else interpret
     gscales = jnp.stack([
         jnp.asarray(qg.global_scale, jnp.float32).reshape(()),
         jnp.asarray(qu.global_scale, jnp.float32).reshape(()),
@@ -197,4 +87,4 @@ def grouped_fp4_ffn(xs: jax.Array, gs: jax.Array,
     return grouped_fp4_ffn_kernel(
         xs, gs, qg.packed, qg.scales, qu.packed, qu.scales,
         qd.packed, qd.scales, gscales, group=group, act=act,
-        interpret=interpret)
+        interpret=_interpret(interpret))

@@ -1,17 +1,20 @@
 """Pallas TPU kernel: on-the-fly NVFP4 (E2M1 + E4M3 group scales) quantization.
 
 This is the paper's "Precision Transformation (T)" stage (§4.3) as a TPU
-kernel: BF16 expert weights resident in HBM are streamed through VMEM in
-``(block_n, block_k)`` tiles, quantized per group of 16 along the
-contraction axis, and written back as packed 4-bit codes + FP8-E4M3-valued
-scales — 4.25 bits/weight of HBM traffic on the way out.  The per-tensor
-``global_scale`` is precomputed at PTQ-calibration time (an input, exactly
-as the paper stores "precomputed scaling factors").
+kernel: a BF16 expert weight stack resident in HBM is streamed through
+VMEM one ``(K, block_n)`` column tile of one expert at a time, quantized
+per group of 16 along the contraction axis K, and written back as packed
+4-bit codes + E4M3-valued scales.  The per-tensor ``global_scale`` is
+computed once over the whole stack (an input, as the paper's precomputed
+scaling factor).
 
-Layout: ``w [N, K]`` (contraction on K) → ``packed u8 [N, K/2]``,
-``scales f32 [N, K/16]``.  Tile sizes default to (256, 512): the tile +
-outputs occupy 256·512·(2+0.5+0.25) ≈ 360 KiB of VMEM, and K blocks are
-multiples of the 128-lane register width.
+Layout (:mod:`repro.kernels.nvfp4`): ``w [G, K, N]`` → ``packed u8
+[G, K/2, N]``, ``scales f32 [G, K/16, N]``.  Each block holds all of K, so
+groups and nibble pairs never straddle a block, and the kernel body is
+``nvfp4.quantize_rows`` — the oracle's own function — on the tile.  N
+blocks are 128 lanes wide (or all of N when N is not a multiple of 128):
+at K = 2048 the BF16 tile is 512 KiB and its f32 temporaries stay well
+inside the default scoped VMEM.
 """
 from __future__ import annotations
 
@@ -21,55 +24,39 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.nvfp4 import (E4M3_MAX, FP4_MAX, GROUP, INV_FP4_MAX,
-                                 e4m3_round as _e4m3_round,
-                                 fp4_code as _fp4_code)
+from repro.kernels.nvfp4 import GROUP, quantize_rows
+
+LANES = 128
 
 
 def _quantize_kernel(gscale_ref, w_ref, packed_ref, scales_ref, *,
                      group: int):
-    w = w_ref[...].astype(jnp.float32)              # [bn, bk]
-    bn, bk = w.shape
-    gs = gscale_ref[0, 0]
-    wg = w.reshape(bn, bk // group, group)
-    amax = jnp.max(jnp.abs(wg), axis=-1)            # [bn, bk/g]
-    s_local = _e4m3_round(amax * INV_FP4_MAX / gs)  # see core/quant.py note
-    s_local = jnp.maximum(s_local, 2.0 ** -9)
-    codes = _fp4_code(wg / (s_local * gs)[..., None])
-    codes = codes.reshape(bn, bk)
-    pair = codes.reshape(bn, bk // 2, 2)
-    packed_ref[...] = (pair[..., 0] | (pair[..., 1] << 4)).astype(jnp.uint8)
-    scales_ref[...] = s_local
+    packed, scales = quantize_rows(w_ref[0], gscale_ref[0, 0], group)
+    packed_ref[0] = packed
+    scales_ref[0] = scales
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("group", "block_n", "block_k",
-                                    "interpret"))
+@functools.partial(jax.jit, static_argnames=("group", "interpret"))
 def quantize_fp4_kernel(w: jax.Array, global_scale: jax.Array, *,
-                        group: int = GROUP, block_n: int = 256,
-                        block_k: int = 512, interpret: bool = False):
-    """w [N,K] bf16/f32 → (packed u8 [N,K/2], scales f32 [N,K/group])."""
-    n, k = w.shape
-    block_n = min(block_n, n)
-    block_k = min(block_k, k)
-    assert n % block_n == 0 and k % block_k == 0, (w.shape, block_n, block_k)
-    assert block_k % (2 * group) == 0
-    grid = (n // block_n, k // block_k)
-    kernel = functools.partial(_quantize_kernel, group=group)
+                        group: int = GROUP, interpret: bool = False):
+    """w [G,K,N] bf16/f32 → (packed u8 [G,K/2,N], scales f32 [G,K/group,N])."""
+    g, k, n = w.shape
+    assert k % (2 * group) == 0, (w.shape, group)
+    block_n = LANES if n % LANES == 0 else n
     return pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_quantize_kernel, group=group),
+        grid=(g, n // block_n),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((block_n, block_k), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1), lambda e, j: (0, 0)),
+            pl.BlockSpec((1, k, block_n), lambda e, j: (e, 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n, block_k // 2), lambda i, j: (i, j)),
-            pl.BlockSpec((block_n, block_k // group), lambda i, j: (i, j)),
+            pl.BlockSpec((1, k // 2, block_n), lambda e, j: (e, 0, j)),
+            pl.BlockSpec((1, k // group, block_n), lambda e, j: (e, 0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, k // 2), jnp.uint8),
-            jax.ShapeDtypeStruct((n, k // group), jnp.float32),
+            jax.ShapeDtypeStruct((g, k // 2, n), jnp.uint8),
+            jax.ShapeDtypeStruct((g, k // group, n), jnp.float32),
         ],
         interpret=interpret,
     )(jnp.asarray(global_scale, jnp.float32).reshape(1, 1), w)

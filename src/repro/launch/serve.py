@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 from repro.configs import ReaLBConfig, get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_for
 from repro.models import transformer as tf
 from repro.models.common import use_mesh
@@ -47,6 +48,7 @@ def main(argv=None):
                     help="LB gate Γ (small default so tiny runs exercise it)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.preset == "tiny":

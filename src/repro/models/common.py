@@ -27,13 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-# jax moved shard_map out of jax.experimental in 0.4.38+ / 0.5; support both
-# spellings so the manual-SPMD layers run on every toolchain we ship against.
-try:
-    shard_map = jax.shard_map
-except AttributeError:                                 # jax <= 0.4.37
-    from jax.experimental.shard_map import shard_map
-
 Tree = Any
 
 # --------------------------------------------------------------------------

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from repro.models.common import shard_map
 
 Tree = Any
 
@@ -77,7 +76,7 @@ def compressed_all_reduce(stacked_grads: Tree, stacked_err: Tree, mesh,
     def fn(g, e):
         return compressed_grad_psum(g, e, axis_name)
 
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec),
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec),
                      out_specs=(spec, spec))(stacked_grads, stacked_err)
 
 

@@ -55,5 +55,5 @@ def shrink_mesh(mesh, lost_axis: str = "pod",
         raise ValueError(f"lost_index {lost} out of [0, {shape[i]})")
     keep = mesh.devices.take([j for j in range(shape[i]) if j != lost],
                              axis=i)
-    from jax.sharding import Mesh
-    return Mesh(keep, axis_names=tuple(names))
+    from repro.launch.mesh import make_mesh
+    return make_mesh(keep.shape, tuple(names), keep)

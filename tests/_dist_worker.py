@@ -21,6 +21,7 @@ jax.config.update("jax_numpy_rank_promotion", "raise")
 from repro.configs import ReaLBConfig, get_config, reduced  # noqa: E402
 from repro.core import ep_moe  # noqa: E402
 from repro.models import transformer as tf  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models.common import use_mesh  # noqa: E402
 
 
@@ -44,7 +45,7 @@ def check_ep_dispatch_matches_local():
     y_ref, _, _ = ep_moe.ep_moe_forward(p, x, cfg, rcfg,
                                         jnp.full((1, 1), 0.9), mod,
                                         mode="dispatch")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         m = jnp.full(ep_moe.moe_state_shape(mesh, 4), 0.9)
         y, _, aux = jax.jit(
@@ -62,7 +63,7 @@ def check_ep_broadcast_matches_local():
     y_ref, _, _ = ep_moe.ep_moe_forward(p, xd, cfg, rcfg,
                                         jnp.full((1, 1), 0.9), md,
                                         mode="broadcast")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         m = jnp.full(ep_moe.moe_state_shape(mesh, 4), 0.9)
         y, _, _ = jax.jit(
@@ -80,7 +81,7 @@ def check_realb_fp4_rank_activates():
     # bias router toward experts 0..1 (rank 0 when ep=4)
     p = dict(p)
     p["router"] = p["router"].at[:, 0].add(3.0).at[:, 1].add(2.5)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     vis = jnp.ones_like(mod)
     with use_mesh(mesh):
         m_on = jnp.zeros(ep_moe.moe_state_shape(mesh, 4))
@@ -106,7 +107,7 @@ def check_chunk_padding_isolated_under_ep():
     rcfg = ReaLBConfig(gate_gamma=10 ** 9)
     x_pad = x.at[:, 8:].set(0.0)                 # second half = padding
     valid = jnp.zeros((4, 16), bool).at[:, :8].set(True)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         m = jnp.full(ep_moe.moe_state_shape(mesh, 4), 0.9)
         y, _, aux = jax.jit(
@@ -128,7 +129,7 @@ def check_placement_identity_bitwise_under_ep():
     to the default (placement=None) path — dispatch and broadcast."""
     cfg, p, x, mod = _moe_setup()
     rcfg = ReaLBConfig(gate_gamma=10 ** 9)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ident = ep_moe.identity_placement(cfg.moe.num_experts, 4)
     with use_mesh(mesh):
         m = jnp.full(ep_moe.moe_state_shape(mesh, 4), 0.9)
@@ -160,7 +161,7 @@ def check_placement_permuted_matches_local_under_ep():
              jnp.asarray(pos % e_loc, jnp.int32))
     p_perm = dict(p, w_gate=p["w_gate"][owner], w_up=p["w_up"][owner],
                   w_down=p["w_down"][owner])
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         m = jnp.full(ep_moe.moe_state_shape(mesh, 4), 0.9)
         for mode, xx, mm in (("dispatch", x, mod),
@@ -204,7 +205,7 @@ def check_virtual_ep_policy_parity():
     m_virt = jnp.zeros((1, 4))            # virtual 4-rank topology, M=0
     y_v, m_v, aux_v = ep_moe.ep_moe_forward(p, x, cfg, rcfg, m_virt, mod,
                                             mode="dispatch")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         shape = ep_moe.moe_state_shape(mesh, 3)
         assert shape == (1, 4), shape     # batch 3 -> replicated group
@@ -236,7 +237,7 @@ def check_replication_identity_bitwise_under_ep():
     bitwise-equal to the default (placement=None) path."""
     cfg, p, x, mod = _moe_setup()
     rcfg = ReaLBConfig(gate_gamma=10 ** 9)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ident = ep_moe.identity_replication(cfg.moe.num_experts, 4)
     with use_mesh(mesh):
         m = jnp.full(ep_moe.moe_state_shape(mesh, 4), 0.9)
@@ -278,7 +279,7 @@ def check_replication_split_under_ep():
 
     y_ref, _, aux_ref = ep_moe.ep_moe_forward(
         p, x, cfg, rcfg, jnp.full((1, 1), 0.9), mod, mode="dispatch")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         m = jnp.full(ep_moe.moe_state_shape(mesh, 4), 0.9)
         y, _, aux = jax.jit(
@@ -325,7 +326,7 @@ def check_perlayer_identity_bitwise_under_ep():
     ident = ep_moe.identity_replication(cfg.moe.num_experts, 4)
     stacked = tuple(jnp.broadcast_to(a, (n_blocks,) + a.shape)
                     for a in ident)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         m = jnp.full(ep_moe.moe_state_shape(mesh, 4), 0.9)
         outs = {}
@@ -349,8 +350,13 @@ def check_perlayer_identity_bitwise_under_ep():
 def check_perlayer_tables_matches_local_under_ep():
     """Depth-varying per-layer permutation tables (each block's weights
     permuted by its own table) on the (2,4) mesh match the local
-    single-device per-layer run and the table-free reference."""
+    single-device per-layer run and the table-free reference.  Capacity
+    is provisioned for every assignment (factor = EP) so that a capacity
+    drop — correct dispatch behaviour, absent from the drop-free local
+    runs — cannot mask or mimic a table mismatch."""
     cfg = reduced(get_config("olmoe-1b-7b"), n_layers=2)
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, capacity_factor=4.0))
     rcfg = ReaLBConfig(gate_gamma=10 ** 9)
     params = tf.init_model(cfg, jax.random.PRNGKey(0))
     e = cfg.moe.num_experts
@@ -386,12 +392,13 @@ def check_perlayer_tables_matches_local_under_ep():
     ref = tf.prefill_forward(params, cfg, rcfg, batch, m1, cache_len=20)
     loc = tf.prefill_forward(perm, cfg, rcfg, batch, m1, cache_len=20,
                              placement=place)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         m = jnp.full(ep_moe.moe_state_shape(mesh, 4), 0.9)
         res = jax.jit(lambda p, m: tf.prefill_forward(
             p, cfg, rcfg, batch, m, cache_len=20,
             placement=place))(perm, m)
+    assert float(res.aux["drop_frac"]) == 0.0, res.aux["drop_frac"]
     e1 = float(jnp.max(jnp.abs(loc.logits - ref.logits)))
     e2 = float(jnp.max(jnp.abs(res.logits - ref.logits)))
     assert e1 < 5e-3 and e2 < 5e-3, (e1, e2)
@@ -422,7 +429,7 @@ def check_async_migrate_chunks_match_sync_under_ep():
         mgr.observe(es)
         return mgr, mgr.maybe_replan(2)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         m_sync, p_sync = mk()
         m_async, p_async = mk()
@@ -499,7 +506,7 @@ def check_replica_capacity_reduced_cap():
                  router=p["router"])
     p_bij = dict(expand_moe_params(wrapped, ident)["blocks"]["l0"]["moe"],
                  router=p["router"])
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         m = jnp.full(ep_moe.moe_state_shape(mesh, 4), 0.9)
         _, _, aux_rep = jax.jit(
@@ -545,7 +552,7 @@ def check_model_train_step_under_mesh():
     m0 = jnp.full((1, 1), 0.9)
     (l_ref, _), g_ref = jax.value_and_grad(loss_fn, has_aux=True)(params, m0)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         m = jnp.full(ep_moe.moe_state_shape(mesh, 4), 0.9)
         (l_d, _), g_d = jax.jit(jax.value_and_grad(
@@ -574,7 +581,7 @@ def check_decode_under_mesh():
     dec_ref = tf.decode_forward(params, cfg, rcfg, db, res_ref.cache,
                                 res_ref.m_state)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         m = jnp.full(ep_moe.moe_state_shape(mesh, 4), 0.9)
         res = jax.jit(lambda p, m: tf.prefill_forward(
@@ -593,10 +600,8 @@ def check_elastic_reshard():
 
     cfg = reduced(get_config("qwen1.5-0.5b"), n_layers=2)
     params = tf.init_model(cfg, jax.random.PRNGKey(0))
-    from jax.sharding import Mesh
-    mesh_a = jax.make_mesh((2, 4), ("data", "model"))
-    mesh_b = Mesh(np.array(jax.devices()[:4]).reshape(1, 4),
-                  ("data", "model"))
+    mesh_a = make_mesh((2, 4), ("data", "model"))
+    mesh_b = make_mesh((1, 4), ("data", "model"), jax.devices()[:4])
     rcfg = ReaLBConfig()
     rng = np.random.default_rng(0)
     tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 16)), jnp.int32)
@@ -652,7 +657,7 @@ def check_weighted_split_under_ep():
                     p, x, cfg, rcfg, m, mod, mode="dispatch",
                     placement=pl))(p_rep, x, m, mod, place)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     y3, _, aux3 = run(base)
     # equal-share schedule == occ % n_rep: the 4-table path is bitwise
     # the 3-table path
@@ -728,7 +733,7 @@ def check_elastic_kill_rejoin_under_ep():
                        mgr.ckpt_group: mgr.state_dict()})
     co = ElasticCoordinator(mgr, ckpt_dir=tmp)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     def run(params):
         place = tuple(jnp.asarray(a) for a in mgr.device_tables())
@@ -843,7 +848,7 @@ def check_collective_census_reconciles():
     cfg, p, x, mod = _moe_setup()
     rcfg = ReaLBConfig(gate_gamma=10 ** 9)
     L = 3
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     def fwd(p, x, m):
         def step(carry, _):
@@ -904,7 +909,7 @@ def check_kernel_fp4_parity_under_ep():
     p["router"] = p["router"].at[:, 0].add(3.0).at[:, 1].add(2.5)
     vis = jnp.ones_like(mod)
     rcfg = ReaLBConfig(gate_gamma=1)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     def run(local):
         if local:

@@ -18,6 +18,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 from repro.checkpoint import ckpt
 from repro.configs import ReaLBConfig, get_config, reduced
 from repro.configs.base import ReplicationConfig
+from repro.launch.mesh import make_mesh
 from repro.placement import PlacementManager
 from repro.placement.migrate import MOE_WEIGHT_KEYS
 from repro.replication import (ReplicaManager, ReplicaSet,
@@ -332,7 +333,7 @@ def test_effective_mesh_drops_dead_slices(tmp_path):
         pytest.skip("needs >= 2 devices")
     mgr = ReplicaManager.from_geometry(4, _rpcfg(), 2, bytes_per_expert=8)
     _, params = _params([ReplicaSet.identity(E, EP, slots_per_rank=SPR)])
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     co = ElasticCoordinator(mgr, ckpt_dir=str(tmp_path))
     state = {"serving": {"params": {}, "m_state": np.zeros((1, 2))},
              mgr.ckpt_group: mgr.state_dict()}

@@ -33,7 +33,7 @@ def test_callback_flagged():
 
 
 def test_f64_flagged_and_waivable():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         closed = jax.make_jaxpr(lambda x: x * 2.0)(np.ones(4, np.float64))
     rep = audit_jaxpr(closed)
     assert any(v.kind == "f64" for v in rep.violations)
@@ -86,8 +86,8 @@ def test_subbyte_dequant_widening_always_legal():
 # collective census
 # --------------------------------------------------------------------------
 def _shard_mapped_psum():
-    from repro.models.common import shard_map
-    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("x",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("x",), jax.devices()[:1])
     P = jax.sharding.PartitionSpec
 
     def inner(x):
@@ -96,8 +96,8 @@ def _shard_mapped_psum():
         y, _ = jax.lax.scan(step, jnp.zeros_like(x), None, length=3)
         return y
 
-    return shard_map(inner, mesh=mesh, in_specs=(P("x"),),
-                     out_specs=P("x"), check_rep=False)
+    return jax.shard_map(inner, mesh=mesh, in_specs=(P("x"),),
+                         out_specs=P("x"), check_vma=False)
 
 
 def test_census_multiplies_scan_trips():

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import quant
-from repro.kernels import fp4_matmul, nvfp4, quantize_fp4
+from repro.kernels import grouped_fp4_ffn, nvfp4, quantize_fp4
 
 
 def _sweep_values():
@@ -23,10 +23,9 @@ def _sweep_values():
 
 def test_modules_share_one_implementation():
     """The anti-drift pin: kernels alias nvfp4, they don't re-implement."""
-    assert quantize_fp4._fp4_code is nvfp4.fp4_code
-    assert quantize_fp4._e4m3_round is nvfp4.e4m3_round
-    assert fp4_matmul._decode_level is nvfp4.decode_level
-    assert fp4_matmul._fake_quant_a4 is nvfp4.fake_quant_a4
+    assert quantize_fp4.quantize_rows is nvfp4.quantize_rows
+    assert grouped_fp4_ffn.dequant_rows is nvfp4.dequant_rows
+    assert grouped_fp4_ffn.fake_quant_a4 is nvfp4.fake_quant_a4
     assert quant.fp4_round is nvfp4.fp4_round
     assert quant.fp4_code is nvfp4.fp4_code
     assert quant.fp4_decode is nvfp4.decode_level
@@ -84,3 +83,59 @@ def test_e4m3_round_idempotent_on_sweep():
     y = nvfp4.e4m3_round(x)
     np.testing.assert_array_equal(np.asarray(nvfp4.e4m3_round(y)),
                                   np.asarray(y))
+
+
+def test_e4m3_round_matches_log2_formula():
+    """The bit-pattern exponent == floor(log2) on values away from binade
+    edges, where the float formula is exact."""
+    rng = np.random.RandomState(1)
+    x = (rng.uniform(1.05, 1.95, 4096) * np.exp2(rng.randint(-12, 10, 4096))
+         * np.where(rng.rand(4096) < 0.5, -1, 1)).astype(np.float32)
+    mag = np.minimum(np.abs(x), 448.0)
+    e = np.clip(np.floor(np.log2(mag)), -6, 8)
+    ulp = np.exp2(e - 3)
+    want = np.sign(x) * np.minimum(np.round(mag / ulp) * ulp, 448.0)
+    np.testing.assert_array_equal(np.asarray(nvfp4.e4m3_round(x)),
+                                  want.astype(np.float32))
+
+
+def test_fake_quant_a4_axis_minus2_is_transpose():
+    """Grouping along sublanes (the in-kernel form) == lanes, transposed."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 24, 64))
+    y = nvfp4.fake_quant_a4(x, 16)
+    yt = nvfp4.fake_quant_a4(x.swapaxes(-1, -2), 16, axis=-2)
+    np.testing.assert_array_equal(np.asarray(yt.swapaxes(-1, -2)),
+                                  np.asarray(y))
+
+
+def test_pack_rows_pairs_rows_j_and_j_plus_group():
+    """The storage format: packed row c·16+j = row 32c+j | row 32c+16+j<<4."""
+    rng = np.random.RandomState(2)
+    codes = rng.randint(0, 16, (3, 96, 40)).astype(np.int32)
+    packed = np.asarray(nvfp4.pack_rows(jnp.asarray(codes)))
+    assert packed.dtype == np.uint8 and packed.shape == (3, 48, 40)
+    c = codes.reshape(3, 3, 2, 16, 40)
+    want = (c[:, :, 0] | (c[:, :, 1] << 4)).reshape(3, 48, 40)
+    np.testing.assert_array_equal(packed, want)
+    np.testing.assert_array_equal(
+        np.asarray(nvfp4.unpack_rows(jnp.asarray(packed))), codes)
+
+
+def test_quantize_rows_groups_along_k():
+    """Scales are per 16 rows of K per column; dequant inverts the pack."""
+    w = jax.random.normal(jax.random.PRNGKey(4), (64, 48)) * 0.2
+    gs = quant.global_scale_for(w)
+    packed, scales = nvfp4.quantize_rows(w, gs)
+    assert packed.shape == (32, 48) and scales.shape == (4, 48)
+    amax = np.abs(np.asarray(w)).reshape(4, 16, 48).max(1)
+    want = np.asarray(nvfp4.e4m3_round(amax * nvfp4.INV_FP4_MAX / gs))
+    np.testing.assert_array_equal(np.asarray(scales),
+                                  np.maximum(want, 2.0 ** -9))
+    dq = np.asarray(nvfp4.dequant_rows(packed, scales, gs))
+    codes = np.asarray(nvfp4.fp4_code(
+        np.asarray(w).reshape(4, 16, 48)
+        / (np.asarray(scales) * np.asarray(gs))[:, None, :])).reshape(64, 48)
+    np.testing.assert_array_equal(
+        dq, (np.asarray(nvfp4.decode_level(codes)).reshape(4, 16, 48)
+             * np.asarray(scales)[:, None, :] * np.asarray(gs)
+             ).reshape(64, 48))

@@ -550,6 +550,31 @@ def _bias_routers_by_depth(params, biases):
     return out
 
 
+def _pair_skew(params, pairs_by_block):
+    """Explicit router skew that holds for every parameter draw.
+
+    For each ``(a, b, w)`` of a block, ``+w`` goes to every row of expert
+    ``a``'s router column and ``-w`` to expert ``b``'s, shifting their
+    logits by ``+w·Σx`` and ``-w·Σx``.  The sign of ``Σx`` varies from
+    token to token, so every token's top-k holds ``a`` or ``b`` and the
+    pair is hot whatever the sign mix.  (A one-sided offset is hot only
+    for the tokens with ``Σx > 0`` — a share that the parameter draw, and
+    so JAX's random stream, decides.)"""
+    out = dict(params)
+    blocks = dict(out["blocks"])
+    lp = dict(blocks["layer0"])
+    moe = dict(lp["moe"])
+    r = moe["router"]
+    for blk, pairs in enumerate(pairs_by_block):
+        for a, b, w in pairs:
+            r = r.at[blk, :, a].add(w).at[blk, :, b].add(-w)
+    moe["router"] = r
+    lp["moe"] = moe
+    blocks["layer0"] = lp
+    out["blocks"] = blocks
+    return out
+
+
 @pytest.mark.slow
 def test_engine_perlayer_identity_matches_baseline(model):
     """A per-layer identity-planner engine generates exactly what a
@@ -574,16 +599,17 @@ def test_engine_perlayer_identity_matches_baseline(model):
 
 @pytest.mark.slow
 def test_engine_perlayer_beats_shared_on_depth_antisymmetric_skew(model):
-    """Depth-antisymmetric router skew (layer 0 and layer 1 hot on
-    complementary experts, so the depth-summed load is near-uniform):
-    the shared planner sees nothing to fix while per-layer planning
-    flattens each layer — strictly lower prefill IB, and migration
-    traffic only for the layers that changed."""
+    """Depth-antisymmetric router skew (layer 0 hot on experts 0-3,
+    layer 1 on the mirror-image experts 7-4, so every EP rank is hot in
+    one layer): the shared planner cannot fix both layers with one table
+    while per-layer planning flattens each layer — strictly lower
+    prefill IB, and migration traffic only for the layers that
+    changed."""
     from repro.serving.engine import Engine
     cfg, params = model
     rcfg = ReaLBConfig(gate_gamma=4)
-    b0 = np.array([3.0, 2.0, 1.0, 0.5, 0.0, 0.0, 0.0, 0.0])
-    params = _bias_routers_by_depth(params, np.stack([b0, b0[::-1]]))
+    params = _pair_skew(params, [[(0, 1, 3.0), (2, 3, 1.0)],
+                                 [(7, 6, 3.0), (5, 4, 1.0)]])
 
     def run(per_layer):
         mgr = PlacementManager(cfg, PlacementConfig(

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.core import quant
+from repro.kernels import nvfp4
 
 try:
     import hypothesis
@@ -44,10 +45,11 @@ def test_fp4_code_decode_roundtrip():
 
 def test_pack_unpack_roundtrip():
     rng = np.random.default_rng(0)
-    codes = rng.integers(0, 16, (4, 32)).astype(np.uint8)
-    packed = quant.pack_u4(jnp.asarray(codes))
-    assert packed.shape == (4, 16)
-    np.testing.assert_array_equal(np.asarray(quant.unpack_u4(packed)), codes)
+    codes = rng.integers(0, 16, (32, 4)).astype(np.uint8)
+    packed = nvfp4.pack_rows(jnp.asarray(codes))
+    assert packed.shape == (16, 4)
+    np.testing.assert_array_equal(np.asarray(nvfp4.unpack_rows(packed)),
+                                  codes)
 
 
 # -- shared check bodies ----------------------------------------------------
@@ -64,12 +66,12 @@ def check_e4m3_idempotent_and_bounded(x):
 
 def check_quantize_roundtrip_error_bound(seed, scale):
     rng = np.random.default_rng(seed)
-    w = (rng.normal(0, scale, (4, 64))).astype(np.float32)
+    w = (rng.normal(0, scale, (64, 4))).astype(np.float32)
     q = quant.quantize_fp4(jnp.asarray(w))
     dq = np.asarray(quant.dequantize_fp4(q))
-    wg = w.reshape(4, 4, 16)
-    amax = np.abs(wg).max(-1, keepdims=True)
-    err = np.abs(dq.reshape(4, 4, 16) - wg)
+    wg = w.reshape(4, 16, 4)                      # groups of 16 along K
+    amax = np.abs(wg).max(1, keepdims=True)
+    err = np.abs(dq.reshape(4, 16, 4) - wg)
     # grid step <= amax/3 around the top; scale rounding <= 6.25% extra
     assert np.all(err <= 0.25 * amax + 1e-7)
 
@@ -121,13 +123,13 @@ def test_fp4_sim_gradient_straight_through():
 def test_matmul_w4a4_matches_manual():
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.normal(0, 1, (8, 32)), jnp.float32)
-    w = jnp.asarray(rng.normal(0, 0.1, (16, 32)), jnp.float32)
+    w = jnp.asarray(rng.normal(0, 0.1, (32, 16)), jnp.float32)   # [K,N]
     q = quant.quantize_fp4(w)
     y = quant.matmul_w4a4(x, q)
     xq = quant.fp4_sim(x)
     wq = quant.dequantize_fp4(q)
     np.testing.assert_allclose(np.asarray(y),
-                               np.asarray(xq) @ np.asarray(wq).T, rtol=2e-5,
+                               np.asarray(xq) @ np.asarray(wq), rtol=2e-5,
                                atol=2e-5)
 
 

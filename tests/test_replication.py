@@ -516,17 +516,43 @@ def test_engine_aborts_staged_plan_on_failed_apply(model, monkeypatch):
     assert mgr.n_migrations >= 1
 
 
+def _pair_skew(params, pairs_by_block):
+    """Explicit router skew that holds for every parameter draw.
+
+    For each ``(a, b, w)`` of a block, ``+w`` goes to every row of expert
+    ``a``'s router column and ``-w`` to expert ``b``'s, shifting their
+    logits by ``+w·Σx`` and ``-w·Σx``.  The sign of ``Σx`` varies from
+    token to token, so every token's top-k holds ``a`` or ``b`` and the
+    pair is hot whatever the sign mix.  (A one-sided offset is hot only
+    for the tokens with ``Σx > 0`` — a share that the parameter draw, and
+    so JAX's random stream, decides.)"""
+    out = dict(params)
+    blocks = dict(out["blocks"])
+    lp = dict(blocks["layer0"])
+    moe = dict(lp["moe"])
+    r = moe["router"]
+    for blk, pairs in enumerate(pairs_by_block):
+        for a, b, w in pairs:
+            r = r.at[blk, :, a].add(w).at[blk, :, b].add(-w)
+    moe["router"] = r
+    lp["moe"] = moe
+    blocks["layer0"] = lp
+    out["blocks"] = blocks
+    return out
+
+
 @pytest.mark.slow
 def test_engine_live_replication_beats_placement_ib(model):
-    """Acceptance: on a hot-expert stream the replica engine performs
-    live replica adds and ends with lower prefill IB than the bijective
-    placement engine on the same stream."""
+    """Acceptance: on a hot-expert stream (every token routed to expert
+    0 or 1) the replica engine performs live replica adds and ends with
+    lower prefill IB than the bijective placement engine on the same
+    stream."""
     from repro.configs import PlacementConfig
     from repro.placement import PlacementManager
     from repro.serving.engine import Engine
     from repro.serving.telemetry import Telemetry
     cfg, params = model
-    params = _bias_router(params)
+    params = _pair_skew(params, [[(0, 1, 3.0)]] * 2)
     rcfg = ReaLBConfig(gate_gamma=4)
 
     def run(mgr, p):

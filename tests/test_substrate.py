@@ -148,10 +148,10 @@ def test_compressed_all_reduce_contract():
     """Host-level shard_map wrapper: on a 1-rank mesh the reduction is the
     int8 quantize/dequantize of the input, and reduced + residual
     reconstructs the gradient exactly (error-feedback invariant)."""
-    from jax.sharding import Mesh
+    from repro.launch.mesh import make_mesh
     from repro.optim.grad_utils import compressed_all_reduce
 
-    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    mesh = make_mesh((1,), ("data",), jax.devices()[:1])
     rng = np.random.default_rng(0)
     grads = {"w": jnp.asarray(rng.normal(0, 0.1, (1, 4, 8)), jnp.float32),
              "b": jnp.asarray(rng.normal(0, 1.0, (1, 8)), jnp.float32)}
@@ -165,3 +165,27 @@ def test_compressed_all_reduce_contract():
         # int8 quantization error bounded by scale = amax/127
         amax = float(jnp.abs(grads[k]).max())
         assert float(jnp.abs(red[k] - grads[k]).max()) <= amax / 127.0 + 1e-9
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is JAX's own and is left
+    alone; otherwise the cache sits at the fixed repo-root .jax_cache/."""
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    sentinel = str(tmp_path / "untouched")
+    jax.config.update("jax_compilation_cache_dir", sentinel)
+    try:
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            got = compile_cache.enable_compile_cache()
+            want = pathlib.Path(__file__).resolve().parents[1] / ".jax_cache"
+            assert got == str(want)
+            assert jax.config.jax_compilation_cache_dir == str(want)
+        else:
+            given = str(tmp_path / env_dir)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", given)
+            assert compile_cache.enable_compile_cache() == given
+            assert jax.config.jax_compilation_cache_dir == sentinel
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
