@@ -226,7 +226,7 @@ def parse_args(argv=None):
                          "(e.g. BENCH_serve.json as a CI artifact)")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="write a Chrome-trace/Perfetto JSON of the run's "
-                         "spans (iter / admit / forward.* / "
+                         "spans (iter / admit / launch.* / "
                          "migration.drain / replan.* / elastic.*); also "
                          "attaches the replan audit log.  Deterministic "
                          "under the virtual clock.  Summarize with "

@@ -405,17 +405,11 @@ def _quantize_experts(w: Dict[str, jax.Array], use_fp4: jax.Array,
 # dispatch path (train / prefill)
 # --------------------------------------------------------------------------
 def _moe_dispatch(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
-                  pol_ep, train, stop_stage=None):
+                  pol_ep, train):
     """x_t [t,D] local tokens; mod_t [t] vision flags; val_t [t] real-token
     flags (False = batch padding); m_vec [pol_ep] AIMD; rep maps logical
     experts onto replica slots strided over ``pol_ep`` policy ranks
-    (== comm.ep on a real EP mesh; a virtual topology when comm.ep == 1).
-
-    ``stop_stage`` (trace-time static) truncates the computation after the
-    named phase and returns that phase's live boundary values — the
-    profiler's instrumented mode jits each cumulative prefix and times it
-    standalone; ``None`` (the default, and the last prefix) is the normal
-    fused layer, so instrumentation shares every op with production."""
+    (== comm.ep on a real EP mesh; a virtual topology when comm.ep == 1)."""
     e_cfg = cfg.moe
     ep, e = comm.ep, cfg.moe.num_experts
     n_slots = rep.slot_owner.shape[0]    # physical weight slots (>= E)
@@ -458,23 +452,15 @@ def _moe_dispatch(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
         else:  # virtual policy topology on one physical rank: any -> all
             use_fp4_rank = jnp.any(dec.use_fp4)
         use_fp4_me = jnp.asarray(False) if train else use_fp4_rank
-    if stop_stage == "route":
-        return gates, flat_p, dec.m_new, load_d, use_fp4_me
 
     with jax.named_scope("weight_gather"):
         w = _gather_weights(p, comm)
-    if stop_stage == "weight_gather":
-        return gates, flat_p, dec.m_new, use_fp4_me, w
 
     # ③ conditional on-the-fly quantization (overlaps with a2a below) ------
     wq = None
     if not train and rcfg.overlap:
         with jax.named_scope("quantize_fp4"):
             wq = _quantize_experts(w, use_fp4_me, rcfg, None)
-    if stop_stage == "quantize_fp4":
-        # under ReaLB-seq / train the transformation has not run here —
-        # its cost lands inside the dispatch prefix instead
-        return gates, flat_p, dec.m_new, use_fp4_me, w if wq is None else wq
 
     # dispatch --------------------------------------------------------------
     # padding tokens are sorted to the back and never claim a capacity
@@ -514,8 +500,6 @@ def _moe_dispatch(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
         with jax.named_scope("quantize_fp4"):
             token = (recv.sum() * 0.0).astype(F32)
             wq = _quantize_experts(w, use_fp4_me, rcfg, token)
-    if stop_stage == "dispatch":
-        return gates, dec.m_new, recv, eid_recv, slot_flat
 
     # ④ balanced local expert compute ---------------------------------------
     with jax.named_scope("expert_gemm"):
@@ -538,8 +522,6 @@ def _moe_dispatch(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
                                        o[1]["w_up"], o[1]["w_down"], act),
                 (xs, w_pad, wq_pad))
         y_buf = jnp.zeros_like(ys).at[order2].set(ys)
-    if stop_stage == "expert_gemm":
-        return gates, dec.m_new, y_buf, slot_flat
 
     with jax.named_scope("combine"):
         ret = comm.a2a(y_buf.reshape(ep, cap, d)).reshape(ep * cap, d)
@@ -569,11 +551,8 @@ def _moe_dispatch(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
 # broadcast path (decode)
 # --------------------------------------------------------------------------
 def _moe_broadcast(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
-                   pol_ep, stop_stage=None):
-    """Decode-regime MoE: tokens replicated over the EP axis.
-
-    ``stop_stage`` — see :func:`_moe_dispatch`; the broadcast path has no
-    a2a, so its prefix vocabulary skips ``dispatch``."""
+                   pol_ep):
+    """Decode-regime MoE: tokens replicated over the EP axis."""
     e_cfg = cfg.moe
     ep, e = comm.ep, e_cfg.num_experts
     n_slots = rep.slot_owner.shape[0]
@@ -606,18 +585,12 @@ def _moe_broadcast(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
             use_fp4_me = dec.use_fp4[comm.my_rank]
         else:
             use_fp4_me = jnp.any(dec.use_fp4)
-    if stop_stage == "route":
-        return gates, flat_p, dec.m_new, load_d, use_fp4_me
 
     with jax.named_scope("weight_gather"):
         w = _gather_weights(p, comm)
-    if stop_stage == "weight_gather":
-        return gates, flat_p, dec.m_new, use_fp4_me, w
 
     with jax.named_scope("quantize_fp4"):
         wq = _quantize_experts(w, use_fp4_me, rcfg, None)
-    if stop_stage == "quantize_fp4":
-        return gates, flat_p, dec.m_new, use_fp4_me, wq
 
     with jax.named_scope("expert_gemm"):
         pidx = flat_p.reshape(t, k)                            # [t,K] placed
@@ -652,8 +625,6 @@ def _moe_broadcast(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
 
         y_e = jax.lax.cond(use_fp4_me, fp4_branch, bf16_branch,
                            (x_t, w, wq))
-    if stop_stage == "expert_gemm":
-        return gates, dec.m_new, y_e, leid
 
     with jax.named_scope("combine"):
         onehot = jax.nn.one_hot(leid, s_loc, dtype=y_e.dtype)  # [t,K,s_loc]
@@ -718,8 +689,7 @@ def ep_moe_forward(p: Params, x: jax.Array, cfg: ModelConfig,
                    mode: str = "dispatch", train: bool = False,
                    fsdp: bool = False,
                    valid: Optional[jax.Array] = None,
-                   placement: Optional[Placement] = None,
-                   stop_stage: Optional[str] = None):
+                   placement: Optional[Placement] = None):
     """MoE layer with ReaLB.  x [B,S,D]; m_state [groups, ep] (see
     :func:`moe_state_shape`); valid [B,S] marks real tokens (None = all) —
     padding still computes but is excluded from the routing stats the
@@ -730,13 +700,7 @@ def ep_moe_forward(p: Params, x: jax.Array, cfg: ModelConfig,
     round-robin token splitting.  The expert weight arrays in ``p`` must
     be stored in the matching *placed* physical-slot order (``[S, ...]``
     with ``S >= num_experts`` under replication).
-    Returns (y, new_m_state, aux_dict).
-
-    ``stop_stage`` (instrumented profiling, local path only): truncate
-    after the named phase (``route`` / ``weight_gather`` /
-    ``quantize_fp4`` / ``dispatch`` / ``expert_gemm``) and return that
-    prefix's raw boundary values instead — see
-    :func:`repro.obs.profiler.time_moe_phases`."""
+    Returns (y, new_m_state, aux_dict)."""
     mesh = current_mesh()
     if modality is None:
         modality = jnp.zeros(x.shape[:2], jnp.bool_)
@@ -759,21 +723,12 @@ def ep_moe_forward(p: Params, x: jax.Array, cfg: ModelConfig,
         comm = _local_comm()
         b, s, d = x.shape
         act = jax.nn.silu if cfg.activation == "swiglu" else jax.nn.gelu
-        fn = partial(_moe_broadcast, stop_stage=stop_stage) \
-            if mode == "broadcast" else partial(
-                _moe_dispatch, train=train, stop_stage=stop_stage)
-        out = fn(x.reshape(b * s, d), modality.reshape(b * s),
-                 valid.reshape(b * s), p, m_state.reshape(-1),
-                 cfg, rcfg, comm, act, rep, pol_ep)
-        if stop_stage is not None:       # instrumented prefix: raw boundary
-            return out
-        y, m_new, aux = out
+        fn = _moe_broadcast if mode == "broadcast" \
+            else partial(_moe_dispatch, train=train)
+        y, m_new, aux = fn(x.reshape(b * s, d), modality.reshape(b * s),
+                           valid.reshape(b * s), p, m_state.reshape(-1),
+                           cfg, rcfg, comm, act, rep, pol_ep)
         return (y.reshape(b, s, d), m_new.reshape(m_state.shape), aux)
-
-    if stop_stage is not None:
-        raise NotImplementedError(
-            "stop_stage instrumentation is local-path only; profile real "
-            "meshes with serve_bench --xprof-out (jax.profiler capture)")
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     ep = sizes["model"]
     row_axes = tuple(a for a in mesh.axis_names if a != "model")

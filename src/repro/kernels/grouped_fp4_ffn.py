@@ -177,6 +177,7 @@ def grouped_fp4_ffn_kernel(xs: jax.Array, gs: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((mp, d), out_dtype),
         interpret=interpret,
+        name="grouped_fp4_ffn",
     )(offs, gmap, xs,
       jnp.asarray(global_scales, jnp.float32).reshape(1, 3),
       gate_packed, gate_scales, up_packed, up_scales,

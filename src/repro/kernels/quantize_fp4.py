@@ -59,4 +59,5 @@ def quantize_fp4_kernel(w: jax.Array, global_scale: jax.Array, *,
             jax.ShapeDtypeStruct((g, k // group, n), jnp.float32),
         ],
         interpret=interpret,
+        name="quantize_fp4",
     )(jnp.asarray(global_scale, jnp.float32).reshape(1, 1), w)

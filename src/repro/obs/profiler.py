@@ -12,8 +12,8 @@ Three jobs, one object:
    ``sum(phase seconds) == forward seconds`` is the reconciliation
    invariant ``benchmarks/profile_report.py`` enforces (the same
    accounting-integrity discipline as ``trace_report.py``).  Real
-   unattributed per-phase wall numbers come from the two instrumented
-   paths below.
+   per-phase device times come from a profiler trace, whose operations
+   carry the ``jax.named_scope`` phases (``bench/run.py --trace 1``).
 2. **MFU / roofline gauges.**  Cumulative ledger flops over cumulative
    measured forward seconds against the single-sourced
    :data:`repro.configs.hw.PEAK_BF16`, plus the compute-vs-memory-vs-
@@ -27,18 +27,7 @@ Three jobs, one object:
    Per-phase drift ratios (cumulative measured / predicted) land in the
    ``costmodel_drift`` gauge.
 
-Instrumented execution mode (:func:`time_moe_phases`) runs the MoE layer
-as separately-jitted cumulative *prefixes* (``stop_stage`` in
-``core/ep_moe.py``), timing each with ``block_until_ready``; phase time
-is the difference of adjacent prefix times.  The full prefix is
-literally the fused computation, so its output is bitwise identical to
-the normal path (pinned by test).  Caveat: prefix timings are
-*unoverlapped* standalone costs — the fused graph overlaps FP4
-quantization with the dispatch all-to-all, so the sum of phases is an
-upper bound on fused time, and the ``dispatch + quantize_fp4`` share is
-exactly the number ROADMAP item 1's Pallas kernel must shrink.
-
-Disabled profiling follows the tracer's null-object discipline:
+Disabled profiling follows the null-object discipline:
 :data:`NULL_PROFILER` is a shared no-op singleton — no stats
 conversion, no clock reads, bitwise-identical engine outputs (pinned by
 ``tests/test_profiler.py``).
@@ -46,21 +35,12 @@ conversion, no clock reads, bitwise-identical engine outputs (pinned by
 from __future__ import annotations
 
 import json
-import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional
 
 import numpy as np
 
 from repro.configs.hw import PEAK_BF16
 from repro.obs.ledger import PHASES, FlopByteLedger, IterLedger
-
-#: MoE phase order of the instrumented prefixes, per dispatch mode.
-MOE_STAGES = {
-    "dispatch": ("route", "weight_gather", "quantize_fp4", "dispatch",
-                 "expert_gemm", "combine"),
-    "broadcast": ("route", "weight_gather", "quantize_fp4",
-                  "expert_gemm", "combine"),
-}
 
 PROFILE_SCHEMA = "repro.profile.v1"
 
@@ -271,61 +251,3 @@ class Profiler:
         with open(path, "w") as f:
             json.dump(doc, f, indent=1)
         return doc
-
-
-# --------------------------------------------------------------------------
-# instrumented execution mode: separately-jitted cumulative prefixes
-# --------------------------------------------------------------------------
-def time_moe_phases(p, x, cfg, rcfg, m_state, *, mode: str = "dispatch",
-                    modality=None, valid=None, placement=None,
-                    repeats: int = 3, warmup: int = 1
-                    ) -> Tuple[Dict[str, float], Any]:
-    """Per-phase wall seconds of one MoE layer via prefix timing.
-
-    Jits the layer once per cumulative ``stop_stage`` prefix, times each
-    with ``block_until_ready`` (min over ``repeats`` after ``warmup``),
-    and reports ``phase[k] = t(prefix_k) − t(prefix_{k−1})`` clamped at
-    zero.  Returns ``(phase_seconds, full_output)`` where
-    ``full_output`` is the final prefix's ``(y, m_new, aux)`` — bitwise
-    identical to ``ep_moe_forward`` without instrumentation (the full
-    prefix *is* the fused computation).
-
-    Local (virtual-EP) path only; quantize timing requires the overlap
-    pipeline (``rcfg.overlap``) — under ReaLB-seq the transformation
-    cost lands inside the ``dispatch`` phase instead.
-    """
-    import jax
-
-    from repro.core import ep_moe
-
-    stages = MOE_STAGES[mode]
-
-    def make(stop):
-        def fn(p_, x_, m_):
-            return ep_moe.ep_moe_forward(
-                p_, x_, cfg, rcfg, m_, modality=modality, valid=valid,
-                mode=mode, placement=placement, stop_stage=stop)
-        return jax.jit(fn)
-
-    def measure(fn):
-        out = None
-        for _ in range(max(warmup, 1)):
-            out = jax.block_until_ready(fn(p, x, m_state))
-        best = float("inf")
-        for _ in range(max(repeats, 1)):
-            t0 = time.perf_counter()
-            out = jax.block_until_ready(fn(p, x, m_state))
-            best = min(best, time.perf_counter() - t0)
-        return best, out
-
-    seconds: Dict[str, float] = {}
-    prev = 0.0
-    full_out = None
-    for stage in stages:
-        stop = None if stage == stages[-1] else stage
-        t, out = measure(make(stop))
-        seconds[stage] = max(t - prev, 0.0)
-        prev = max(t, prev)
-        if stop is None:
-            full_out = out
-    return seconds, full_out

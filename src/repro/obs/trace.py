@@ -1,26 +1,35 @@
-"""Low-overhead span tracer with Chrome-trace (Perfetto) JSON export.
+"""One span API with two sinks: the profiler, always, and Chrome JSON.
 
 The serving stack answers "where inside the iteration did the time go"
 with *nested spans*: the engine opens one ``iter`` span per serving
-iteration and nests admission / chunk-forward / decode / migration-drain
-/ table-commit spans inside it; the managers wrap their planning, the
+iteration and nests admission, input building, program launches, the
+sampling sync, the statistics pull, retirement and migration spans
+inside it; the managers wrap their planning, the
 :class:`~repro.serving.async_migrate.MigrationExecutor` wraps each chunk
 batch, the :class:`~repro.serving.elastic.ElasticCoordinator` stamps its
-events as instants.  Spans read the *engine clock* — under the virtual
-clock of a seeded benchmark run the whole trace is deterministic and
-CI-diffable; under wall clocks it is an honest profile.
+events as instants.
 
-Zero-cost when disabled: :data:`NULL_TRACER` is a shared singleton whose
-``span``/``instant``/``complete`` are no-ops returning one cached null
-span — no dict allocation, no clock read, nothing recorded — so an
-engine built without a tracer is bitwise identical to one predating the
-obs layer.  Hot loops guard annotation work with ``tracer.enabled``.
+Every span enters ``jax.profiler.TraceAnnotation("host.engine." +
+name)``.  While a profiler trace is being collected the span lands on
+the trace's host plane, on the same clock as the device's operations, so
+each device idle gap can be put down to the host stage around it; at any
+other time the annotation is a no-op in C++.  :data:`DEFAULT_TRACER`,
+the engine's default, has only this sink: it records nothing, reads no
+clock and stays ``enabled = False``.  Span args are built only when
+``collecting`` is true (the profiler is running, or the tracer records),
+so an untraced engine builds no arg dicts and is bitwise identical to
+one that traces.
 
-Export follows the Chrome Trace Event format (the JSON Perfetto and
+:class:`Tracer` also records each span on the *engine clock* — under the
+virtual clock of a seeded benchmark run the whole trace is
+deterministic and CI-diffable; under wall clocks it is an honest profile
+— and exports Chrome Trace Event JSON (the format Perfetto and
 ``chrome://tracing`` load): ``X`` complete events with microsecond
 ``ts``/``dur``, ``i`` instants, one process/thread.  Extra run metadata
 rides in the top-level ``metadata`` object (ignored by viewers, read by
 ``benchmarks/trace_report.py`` for stall-vs-hidden reconciliation).
+``instant`` and ``complete`` are Chrome-only: a retroactive complete
+event cannot be annotated.
 """
 from __future__ import annotations
 
@@ -28,13 +37,34 @@ import json
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
+#: Prefix of every span name on the profiler's host plane.
+PROFILER_PREFIX = "host.engine."
+
+
+class Annotation(TraceAnnotation):
+    """A profiler span with the tracer's ``set``; args reach the trace
+    only while the profiler collects."""
+
+    def set(self, **kw) -> "Annotation":
+        self.set_metadata(**kw)
+        return self
+
+
+def profiler_collecting() -> bool:
+    """A profiler trace is being collected in this process."""
+    return TraceAnnotation.is_enabled()
+
 
 class Span:
-    """One open span; annotate with :meth:`set`, close via ``with``."""
-    __slots__ = ("_tracer", "name", "cat", "t0", "args")
+    """One open recorded span; annotate with :meth:`set`, close via
+    ``with``.  Forwarded to the profiler as an :class:`Annotation`."""
+    __slots__ = ("_tracer", "_ann", "name", "cat", "t0", "args")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str):
         self._tracer = tracer
+        self._ann = Annotation(PROFILER_PREFIX + name)
         self.name, self.cat = name, cat
         self.t0 = 0.0
         self.args: Optional[Dict[str, Any]] = None
@@ -45,9 +75,12 @@ class Span:
             self.args = kw
         else:
             self.args.update(kw)
+        if profiler_collecting():
+            self._ann.set_metadata(**kw)
         return self
 
     def __enter__(self) -> "Span":
+        self._ann.__enter__()
         self.t0 = self._tracer.clock()
         self._tracer._depth += 1
         return self
@@ -57,35 +90,25 @@ class Span:
         tr._depth -= 1
         tr._events.append(("X", self.name, self.cat, self.t0,
                            tr.clock() - self.t0, self.args))
+        self._ann.__exit__(*exc)
         return False
 
 
-class _NullSpan:
-    """The shared do-nothing span of :class:`NullTracer`."""
-    __slots__ = ()
+class ProfilerTracer:
+    """The default tracer: each span is a profiler :class:`Annotation`,
+    nothing is recorded and no clock is read.
 
-    def set(self, **kw) -> "_NullSpan":
-        return self
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
-class NullTracer:
-    """Tracing disabled: every call is a no-op on shared singletons.
-
-    ``enabled`` is the hot-loop guard — code computing span annotations
-    checks it first so a disabled tracer costs one attribute read."""
+    ``enabled`` (False) guards work only a recording tracer consumes;
+    ``collecting`` guards span args, which the profiler keeps while it
+    collects."""
     enabled = False
 
-    def span(self, name: str, cat: str = "serving") -> _NullSpan:
-        return _NULL_SPAN
+    @property
+    def collecting(self) -> bool:
+        return profiler_collecting()
+
+    def span(self, name: str, cat: str = "serving") -> Annotation:
+        return Annotation(PROFILER_PREFIX + name)
 
     def instant(self, name: str, cat: str = "serving",
                 args: Optional[Dict[str, Any]] = None) -> None:
@@ -97,14 +120,15 @@ class NullTracer:
         pass
 
 
-NULL_TRACER = NullTracer()
+DEFAULT_TRACER = ProfilerTracer()
 
 
 class Tracer:
     """Records spans/instants against ``clock`` (seconds; the engine's
     virtual clock for deterministic traces, ``time.perf_counter`` for
-    wall profiles)."""
+    wall profiles), and forwards each span to the profiler."""
     enabled = True
+    collecting = True
 
     def __init__(self, clock: Optional[Callable[[], float]] = None):
         self.clock = clock if clock is not None else time.perf_counter

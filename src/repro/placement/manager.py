@@ -37,7 +37,7 @@ from typing import Dict, List, Optional, Union
 import numpy as np
 
 from repro.configs.base import ModelConfig, PlacementConfig
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import DEFAULT_TRACER
 from repro.placement import migrate
 from repro.placement.planner import plan_placement
 from repro.placement.predictor import EWMAPredictor
@@ -83,7 +83,7 @@ class ReplanDiscipline:
     # every maybe_replan call ends in exactly one audit verdict; planning
     # attempts past the cadence gate get a tracer span
     audit = None                    # repro.obs.audit.ReplanAudit
-    tracer = NULL_TRACER            # repro.obs.trace.Tracer
+    tracer = DEFAULT_TRACER         # repro.obs.trace.Tracer
     _kind = "manager"               # audit/span label: placement|replication
     _skip = None                    # why the last _cadence said no
     _verdict = "no-cadence"         # the last maybe_replan verdict

@@ -44,7 +44,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from repro.checkpoint import ckpt as ckpt_lib
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import DEFAULT_TRACER
 from repro.placement.migrate import MOE_WEIGHT_KEYS, moe_param_paths
 
 Tree = Any
@@ -97,7 +97,7 @@ class ElasticCoordinator:
     a singleton is refused (replicated-only losses still work).
     """
 
-    tracer = NULL_TRACER            # optional span tracer (engine-shared)
+    tracer = DEFAULT_TRACER         # optional span tracer (engine-shared)
 
     def _emit(self, ev: Dict) -> None:
         """Append one elastic event; mirror it as a trace instant so the

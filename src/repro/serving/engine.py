@@ -68,7 +68,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from functools import partial
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -80,7 +80,7 @@ from repro.core import ep_moe
 from repro.models import transformer as tf
 from repro.models.common import current_mesh
 from repro.obs.profiler import NULL_PROFILER
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import DEFAULT_TRACER
 from repro.serving.scheduler import Request, Scheduler
 from repro.serving.telemetry import Telemetry
 
@@ -115,6 +115,11 @@ class IterStats:
     #                              dead rank's zeroed slots)
 
 
+#: Layer-summed scalars of ``aux`` the per-iteration statistics read.
+STAT_SCALARS = ("ib_global", "fp4_ranks", "gate_open", "drop_frac",
+                "split_frac")
+
+
 def _bucket(n: int, lo: int = 8) -> int:
     """Round a chunk length up to a power of two (bounds jit recompiles)."""
     b = lo
@@ -144,13 +149,14 @@ class Engine:
         # against unsanctioned device->host syncs and counts per-entry
         # jit compilations (zero recompiles after warmup).
         self.sentinel = NULL_SENTINEL if sentinel is None else sentinel
-        # span tracer (repro.obs.trace.Tracer); None -> the shared no-op
-        # singleton, whose calls record nothing and read no clock — an
-        # untraced engine is bitwise identical to one predating the obs
-        # layer.  When given, the tracer is shared with the manager and
+        # span tracer (repro.obs.trace.Tracer); None -> the shared
+        # default, whose spans only annotate the profiler (a no-op unless
+        # a trace is being collected): it records nothing and reads no
+        # clock — an untraced engine is bitwise identical to a traced
+        # one.  When given, the tracer is shared with the manager and
         # the elastic coordinator so their spans land on the same
         # timeline.
-        self.tracer = NULL_TRACER if tracer is None else tracer
+        self.tracer = DEFAULT_TRACER if tracer is None else tracer
         if tracer is not None:
             if placement is not None:
                 placement.tracer = tracer
@@ -250,6 +256,9 @@ class Engine:
                 "elastic coordinator must wrap this engine's manager"
         self._place_cache = None                  # device copy of the table
         self._it = 0
+        # (admitted, chunk rows, chunk tokens, decode rows) of the last
+        # iteration: the iter span's args
+        self._iter_rows = (0, 0, 0, 0)
         self.cache = tf.init_cache(cfg, max_slots, max_len)
         groups, ep = ep_moe.moe_state_shape(current_mesh(), max_slots,
                                             virtual_ep=virtual_ep)
@@ -579,8 +588,10 @@ class Engine:
 
     def _sample(self, logits: jax.Array) -> np.ndarray:
         # sampling is a sanctioned sync: the generated token must reach
-        # the host to extend the sequence (the one pull serving requires)
-        with self.sentinel.sanctioned("sample"):
+        # the host to extend the sequence (the one pull serving requires);
+        # it waits for the program that produced the logits
+        with self.tracer.span("sample", cat="engine"), \
+                self.sentinel.sanctioned("sample"):
             if self.temperature <= 0:
                 return np.asarray(jnp.argmax(logits, axis=-1), np.int32)
             self.key, sub = jax.random.split(self.key)
@@ -606,38 +617,71 @@ class Engine:
     def _record_stats(self, *, phase: str, n_active: int, tokens: int,
                       batch_tokens: int, aux: Dict[str, Any],
                       fwd_s: float = 0.0):
+        trc = self.tracer
+        with trc.span("stats.pull", cat="engine") as sp:
+            host = self._pull_stats(aux)
+            if trc.collecting:
+                sp.set(transfers=len(host))
+        with trc.span("stats.observe", cat="engine"):
+            self._observe_stats(phase=phase, n_active=n_active,
+                                tokens=tokens, batch_tokens=batch_tokens,
+                                host=host, fwd_s=fwd_s)
+
+    def _pull_stats(self, aux: Dict[str, Any]) -> Dict[str, Any]:
+        """Every device->host transfer of ``aux`` the statistics read, one
+        per entry of the result: the stacked ``moe_stats``, the
+        layer-summed scalars, and the expert / slot loads where a manager
+        or a recovery reads them."""
         # moe_stats: [n_blocks, 2, groups, ep] stacked (load_d, vis_d) rows
-        ms = np.asarray(aux["moe_stats"], np.float64)
+        host = {"moe_stats": np.asarray(aux["moe_stats"], np.float64)}
+        for k in STAT_SCALARS:
+            if k in aux:
+                host[k] = float(aux[k])
+        pl = self._placement
+        recovering = self._elastic is not None and self._elastic.recovering
+        if "expert_stats" in aux and (pl is not None or recovering):
+            host["expert_stats"] = np.asarray(aux["expert_stats"])
+        if pl is not None and "expert_stats" in aux and "slot_stats" in aux \
+                and (hasattr(pl, "observe_slots")
+                     or (self.telemetry is not None
+                         and hasattr(pl, "rank_heatmap"))):
+            host["slot_stats"] = np.asarray(aux["slot_stats"])
+        return host
+
+    def _observe_stats(self, *, phase: str, n_active: int, tokens: int,
+                       batch_tokens: int, host: Dict[str, Any],
+                       fwd_s: float):
+        ms = host["moe_stats"]
         load_sum, vis_sum = float(ms[:, 0].sum()), float(ms[:, 1].sum())
         mig_bytes, mig_s, mig_hidden = self._pending_migration
         self._pending_migration = (0, 0.0, 0.0)
         stat = IterStats(
             n_active=n_active, tokens=tokens,
-            ib_global=float(aux["ib_global"]) / self._n_moe,
-            fp4_ranks=float(aux["fp4_ranks"]) / self._n_moe,
-            gate_open=float(aux["gate_open"]) / self._n_moe,
+            ib_global=host["ib_global"] / self._n_moe,
+            fp4_ranks=host["fp4_ranks"] / self._n_moe,
+            gate_open=host["gate_open"] / self._n_moe,
             phase=phase, t_wall=self.clock(), batch_tokens=batch_tokens,
             vis_frac=vis_sum / max(load_sum, 1.0),
-            drop_frac=float(aux["drop_frac"]) / self._n_moe,
+            drop_frac=host["drop_frac"] / self._n_moe,
             migration_bytes=mig_bytes, migration_s=mig_s,
             migration_hidden_s=mig_hidden,
-            split_frac=float(aux.get("split_frac", 0.0)) / self._n_moe)
+            split_frac=host.get("split_frac", 0.0) / self._n_moe)
         if self._elastic is not None and self._elastic.recovering:
             stat.n_unroutable = int(self._elastic.lost_experts.size)
-            if "expert_stats" in aux:
+            if "expert_stats" in host:
                 stat.lost_tokens = self._elastic.lost_token_count(
-                    np.asarray(aux["expert_stats"]))
+                    host["expert_stats"])
         self.stats.append(stat)
-        if self._placement is not None and "expert_stats" in aux:
+        if self._placement is not None and "expert_stats" in host:
             # [n_blocks, 2, E] per-MoE-layer expert loads -> predictor
             # (decode iterations feed the decode window when configured)
-            self._placement.observe(np.asarray(aux["expert_stats"]),
+            self._placement.observe(host["expert_stats"],
                                     decode=(phase == "decode"))
             if hasattr(self._placement, "observe_slots") \
-                    and "slot_stats" in aux:
+                    and "slot_stats" in host:
                 # [n_blocks, 2, S] post-split physical-slot loads ->
                 # replica-utilization accounting
-                self._placement.observe_slots(np.asarray(aux["slot_stats"]))
+                self._placement.observe_slots(host["slot_stats"])
             gate = getattr(self._placement, "cost_gate", None)
             if gate is not None and hasattr(gate, "observe_iter"):
                 # calibrated replan gate: measured routed tokens (and the
@@ -648,10 +692,8 @@ class Engine:
                 # realized [n_blocks, ep] rank loads under the routable
                 # tables -> expert-load heatmap + prediction accuracy
                 self.telemetry.record_rank_heatmap(
-                    self._placement.rank_heatmap(
-                        np.asarray(aux["expert_stats"]),
-                        np.asarray(aux["slot_stats"])
-                        if "slot_stats" in aux else None))
+                    self._placement.rank_heatmap(host["expert_stats"],
+                                                 host.get("slot_stats")))
         if self.telemetry is not None:
             self.telemetry.record_iter(stat)
         if self.profiler.enabled:
@@ -677,9 +719,12 @@ class Engine:
             self.telemetry.record_request(req)
 
     # -- prefill paths -------------------------------------------------------
-    def _first_token(self, req: Request, tok: int):
+    def _first_token(self, req: Request, tok: int, t: float):
+        """``t``: the engine clock right after the sample that brought
+        ``tok`` to the host."""
         req.generated.append(tok)
-        req.first_token_time = self.clock()
+        req.token_times.append(t)
+        req.first_token_time = t
         self.pos[req.slot] = req.prompt_len
         self.last_tok[req.slot] = tok
         self.decode_ready[req.slot] = True
@@ -702,16 +747,17 @@ class Engine:
                               np.float32),
                 jnp.dtype(self.cfg.param_dtype))[None]
         t_fwd = self.clock()
-        with self.tracer.span("forward.prefill", cat="forward") as sp:
+        with self.tracer.span("launch.prefill", cat="engine") as sp:
             logits, new_cache, self.m_state, aux = self._prefill_one(
                 self.params, self.m_state, batch, self._place_args())
             self._tick(req.prompt_len)
-            if self.tracer.enabled:
+            if self.tracer.collecting:
                 sp.set(tokens=req.prompt_len)
         fwd_s = self.clock() - t_fwd
         self._insert_cache(req.slot, new_cache)
         req.prefill_pos = req.prompt_len
-        self._first_token(req, int(self._sample(logits)[0]))
+        tok = int(self._sample(logits)[0])
+        self._first_token(req, tok, self.clock())
         self._record(phase="prefill", n_active=1, tokens=req.prompt_len,
                      batch_tokens=req.prompt_len, aux=aux, fwd_s=fwd_s)
 
@@ -729,37 +775,43 @@ class Engine:
             budget -= take
         return plan
 
-    def _chunk_prefill_step(self) -> int:
-        plan = self._plan_chunks()
-        if not plan:
-            return 0
-        s_bucket = _bucket(max(take for _, take in plan))
-        b = self.max_slots
-        tokens = np.zeros((b, s_bucket), np.int32)
-        modality = np.zeros((b, s_bucket), bool)
-        start = np.zeros(b, np.int32)
-        chunk_len = np.zeros(b, np.int32)
-        for slot, take in plan:
-            req = self.scheduler.active[slot]
-            p0 = req.prefill_pos
-            tokens[slot, :take] = req.tokens[p0:p0 + take]
-            modality[slot, :take] = req.modality[p0:p0 + take]
-            start[slot] = p0
-            chunk_len[slot] = take
+    def _chunk_prefill_step(self) -> Tuple[int, int]:
+        """One batched prefill chunk; returns its (rows, prompt tokens)."""
+        trc = self.tracer
+        with trc.span("chunk.inputs", cat="engine"):
+            plan = self._plan_chunks()
+            if not plan:
+                return 0, 0
+            s_bucket = _bucket(max(take for _, take in plan))
+            b = self.max_slots
+            tokens = np.zeros((b, s_bucket), np.int32)
+            modality = np.zeros((b, s_bucket), bool)
+            start = np.zeros(b, np.int32)
+            chunk_len = np.zeros(b, np.int32)
+            for slot, take in plan:
+                req = self.scheduler.active[slot]
+                p0 = req.prefill_pos
+                tokens[slot, :take] = req.tokens[p0:p0 + take]
+                modality[slot, :take] = req.modality[p0:p0 + take]
+                start[slot] = p0
+                chunk_len[slot] = take
+            args = (jnp.asarray(tokens), jnp.asarray(start),
+                    jnp.asarray(chunk_len), jnp.asarray(modality),
+                    self._place_args())
         t_fwd = self.clock()
-        with self.tracer.span("forward.chunk", cat="forward") as sp:
+        with trc.span("launch.chunk", cat="engine") as sp:
             logits, self.cache, self.m_state, aux = self._chunk(
-                self.params, self.cache, self.m_state, jnp.asarray(tokens),
-                jnp.asarray(start), jnp.asarray(chunk_len),
-                jnp.asarray(modality), self._place_args())
+                self.params, self.cache, self.m_state, *args)
             self._tick(b * s_bucket)
-            if self.tracer.enabled:
+            if trc.collecting:
                 sp.set(slots=len(plan), batch_tokens=b * s_bucket)
         fwd_s = self.clock() - t_fwd
         completing = [slot for slot, take in plan
                       if self.scheduler.active[slot].prefill_pos + take
                       >= self.scheduler.active[slot].prompt_len]
-        toks = self._sample(logits) if completing else None
+        if completing:
+            toks = self._sample(logits)
+            t_tok = self.clock()
         n_tok = 0
         for slot, take in plan:
             req = self.scheduler.active[slot]
@@ -767,20 +819,23 @@ class Engine:
             n_tok += take
             if req.prefill_pos >= req.prompt_len:
                 self._prefill_fifo.remove(slot)
-                self._first_token(req, int(toks[slot]))
+                self._first_token(req, int(toks[slot]), t_tok)
         self._record(phase="prefill", n_active=len(plan), tokens=n_tok,
                      batch_tokens=b * s_bucket, aux=aux, fwd_s=fwd_s)
-        return n_tok
+        return len(plan), n_tok
 
     # -- the iteration --------------------------------------------------------
     def step(self) -> int:
         """One continuous-batching iteration. Returns #active sequences."""
         trc = self.tracer
-        if not trc.enabled:
-            return self._step()
         with trc.span("iter", cat="engine") as sp:
             n = self._step()
-            sp.set(it=self._it, n_active=n, **self.profiler.span_args())
+            if trc.collecting:
+                admitted, chunk_rows, chunk_tokens, decode_rows = \
+                    self._iter_rows
+                sp.set(it=self._it, n_active=n, admitted=admitted,
+                       chunk_rows=chunk_rows, chunk_tokens=chunk_tokens,
+                       decode_rows=decode_rows, **self.profiler.span_args())
         return n
 
     def _step(self) -> int:
@@ -806,9 +861,10 @@ class Engine:
         # then re-derive the replica-aware dispatch capacity from the
         # current prediction (migrated or not — drift under an unchanged
         # set must still re-grow a shrunk buffer)
-        self._maybe_migrate()
         if self._placement is not None:
-            self._maybe_resize_capacity()
+            with self.tracer.span("migrate", cat="migration"):
+                self._maybe_migrate()
+                self._maybe_resize_capacity()
         # everything up to here is the sanctioned between-iteration
         # window (faults, migration drains, resize re-jits); the rest of
         # the iteration is the hot loop the sentinel guards against
@@ -828,10 +884,13 @@ class Engine:
             self._prefill_fifo = [s for s in self._prefill_fifo
                                   if s in self.scheduler.active]
         # 1) admit new requests; route each to the chunked or one-shot path
-        with self.tracer.span("admit", cat="engine") as sp:
-            n_admitted = 0
-            for req in self.scheduler.admit():
-                n_admitted += 1
+        trc = self.tracer
+        oneshot = []
+        with trc.span("admit", cat="engine") as sp:
+            admitted = self.scheduler.admit()
+            t_admit = self.clock() if admitted else 0.0
+            for req in admitted:
+                req.admit_time = t_admit
                 self.active_mask[req.slot] = True
                 self.decode_ready[req.slot] = False
                 self.mod_state[req.slot] = req.decode_modality
@@ -839,19 +898,24 @@ class Engine:
                     req.prefill_pos = 0
                     self._prefill_fifo.append(req.slot)
                 else:
-                    self._prefill_oneshot(req)
-            if self.tracer.enabled:
-                sp.set(admitted=n_admitted)
+                    oneshot.append(req)
+            if trc.collecting:
+                sp.set(admitted=len(admitted))
+        for req in oneshot:
+            self._prefill_oneshot(req)
 
         # 2) one batched chunk of prefill work across all pending slots
+        chunk_rows = chunk_tokens = 0
         if self._prefill_fifo:
-            self._chunk_prefill_step()
+            chunk_rows, chunk_tokens = self._chunk_prefill_step()
 
-        self.scheduler.retire()
-        for s in range(self.max_slots):
-            self.active_mask[s] = s in self.scheduler.active
-            if not self.active_mask[s]:
-                self.decode_ready[s] = False
+        with trc.span("retire", cat="engine"):
+            self.scheduler.retire()
+            for s in range(self.max_slots):
+                self.active_mask[s] = s in self.scheduler.active
+                if not self.active_mask[s]:
+                    self.decode_ready[s] = False
+        self._iter_rows = (len(admitted), chunk_rows, chunk_tokens, 0)
 
         if not self.scheduler.active:
             self._observe_iter_s(t_step0)
@@ -863,26 +927,28 @@ class Engine:
         ready = self.decode_ready & self.active_mask
         n_active = 0
         if ready.any():
-            tokens = jnp.asarray(self.last_tok[:, None], jnp.int32)
-            pos = jnp.asarray(np.where(ready, self.pos, self.max_len),
-                              jnp.int32)
-            modality = jnp.asarray(
-                np.where(ready, self.mod_state, False)[:, None])
+            with trc.span("decode.inputs", cat="engine"):
+                args = (jnp.asarray(self.last_tok[:, None], jnp.int32),
+                        jnp.asarray(np.where(ready, self.pos, self.max_len),
+                                    jnp.int32),
+                        jnp.asarray(
+                            np.where(ready, self.mod_state, False)[:, None]),
+                        jnp.asarray(ready[:, None]), self._place_args())
             t_fwd = self.clock()
-            with self.tracer.span("forward.decode", cat="forward") as sp:
+            with trc.span("launch.decode", cat="engine") as sp:
                 logits, self.cache, self.m_state, aux = self._decode(
-                    self.params, self.cache, self.m_state, tokens, pos,
-                    modality, jnp.asarray(ready[:, None]),
-                    self._place_args())
+                    self.params, self.cache, self.m_state, *args)
                 self._tick(self.max_slots)
-                if self.tracer.enabled:
+                if trc.collecting:
                     sp.set(batch_tokens=self.max_slots,
                            ready=int(ready.sum()))
             fwd_s = self.clock() - t_fwd
             toks = self._sample(logits)
+            t_tok = self.clock()
             for slot, req in list(self.scheduler.active.items()):
                 if ready[slot] and not req.done:
                     req.generated.append(int(toks[slot]))
+                    req.token_times.append(t_tok)
                     self.last_tok[slot] = int(toks[slot])
                     self.pos[slot] += 1
                     n_active += 1
@@ -890,7 +956,9 @@ class Engine:
                         self._finish(req)
             self._record(phase="decode", n_active=n_active, tokens=n_active,
                          batch_tokens=self.max_slots, aux=aux, fwd_s=fwd_s)
-        self.scheduler.retire()
+        with trc.span("retire", cat="engine"):
+            self.scheduler.retire()
+        self._iter_rows = (len(admitted), chunk_rows, chunk_tokens, n_active)
         self._observe_iter_s(t_step0)
         return max(n_active, len(self._prefill_fifo))
 

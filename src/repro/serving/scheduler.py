@@ -32,10 +32,14 @@ class Request:
     arrival_time: Optional[float] = None  # engine-clock submission time;
     # None = stamp with the engine clock at submit()
 
-    # runtime state
+    # runtime state; times are on the engine clock
     generated: List[int] = dataclasses.field(default_factory=list)
     slot: int = -1
     prefill_pos: int = 0             # prompt tokens already prefilled
+    admit_time: Optional[float] = None    # the scheduler gave it a slot
+    # one stamp per generated token, read right after the sample that
+    # brought it to the host (token_times[0] == first_token_time)
+    token_times: List[float] = dataclasses.field(default_factory=list)
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
 

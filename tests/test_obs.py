@@ -5,16 +5,17 @@ Telemetry, the managers and the engine (trace/accounting
 reconciliation, exactly-one-audit-event-per-maybe_replan, bitwise
 parity with tracing disabled)."""
 import json
+import pathlib
 
 import numpy as np
 import pytest
 
 from repro.configs import (PlacementConfig, ReaLBConfig, ReplicationConfig,
                            get_config, reduced)
-from repro.obs import (NULL_TRACER, Counter, Gauge, HeatmapRecorder,
+from repro.obs import (DEFAULT_TRACER, Counter, Gauge, HeatmapRecorder,
                        Histogram, MetricsRegistry, PredictionTracker,
                        ReplanAudit, Tracer, validate_chrome_trace)
-from repro.obs.trace import load_trace
+from repro.obs.trace import PROFILER_PREFIX, Annotation, load_trace
 from repro.placement import PlacementManager
 from repro.replication import ReplicaManager
 from repro.serving.telemetry import Telemetry, percentile, summarize
@@ -196,7 +197,7 @@ def test_tracer_spans_instants_and_export(tmp_path):
     tr = Tracer(clock=lambda: t[0])
     with tr.span("iter", cat="engine") as sp:
         t[0] = 0.5
-        with tr.span("forward.chunk") as inner:
+        with tr.span("launch.chunk") as inner:
             t[0] = 2.0
             inner.set(tokens=128)
         sp.set(it=3).set(n_active=2)                   # set() merges
@@ -209,7 +210,7 @@ def test_tracer_spans_instants_and_export(tmp_path):
         and obj["displayTimeUnit"] == "ms"
     xs = [e for e in events if e["ph"] == "X"]
     # inner span closed first (append order), times in microseconds
-    assert xs[0]["name"] == "forward.chunk"
+    assert xs[0]["name"] == "launch.chunk"
     assert xs[0]["ts"] == pytest.approx(0.5e6)
     assert xs[0]["dur"] == pytest.approx(1.5e6)
     assert xs[1]["args"] == {"it": 3, "n_active": 2}
@@ -238,13 +239,80 @@ def test_validate_chrome_trace_rejects_malformed():
 
 
 def test_null_tracer_is_inert_singletons():
-    assert NULL_TRACER.enabled is False
-    sp = NULL_TRACER.span("anything")
-    assert sp is NULL_TRACER.span("other")             # shared null span
+    """The default tracer records nothing: one shared instance, its spans
+    only profiler annotations, instants and completes no-ops, and no
+    span args wanted while no profiler trace is collected."""
+    assert DEFAULT_TRACER.enabled is False
+    assert DEFAULT_TRACER.collecting is False
+    sp = DEFAULT_TRACER.span("anything")
+    assert isinstance(sp, Annotation)
     with sp as s:
         assert s.set(a=1) is s
-    NULL_TRACER.instant("x")
-    NULL_TRACER.complete("x", 0.0, 1.0)                # all no-ops
+    DEFAULT_TRACER.instant("x")
+    DEFAULT_TRACER.complete("x", 0.0, 1.0)                # all no-ops
+
+
+def _profiled_spans(path):
+    """The ``host.engine.*`` spans of the profiler trace under ``path``,
+    read from its host plane: (name less the prefix, start ns, end ns,
+    args), in start order, outer spans first."""
+    from jax.profiler import ProfileData
+    f = sorted(pathlib.Path(path).rglob("*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(f)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(PROFILER_PREFIX):
+                    out.append((ev.name[len(PROFILER_PREFIX):], ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def test_default_tracer_spans_reach_the_profiler(tmp_path):
+    """Under a profiler trace the default tracer's spans land on the host
+    plane as ``host.engine.<name>``, nested, with their args; instants
+    stay Chrome-only."""
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert DEFAULT_TRACER.collecting is True
+        with DEFAULT_TRACER.span("iter") as sp:
+            sp.set(it=7)
+            with DEFAULT_TRACER.span("sample"):
+                pass
+        DEFAULT_TRACER.instant("dispatch.policy")
+    finally:
+        jax.profiler.stop_trace()
+    assert DEFAULT_TRACER.collecting is False
+    spans = _profiled_spans(tmp_path)
+    assert [n for n, *_ in spans] == ["iter", "sample"]
+    (_, a0, b0, args), (_, a1, b1, _) = spans
+    assert a0 <= a1 <= b1 <= b0 and args["it"] == 7
+
+
+def test_recording_tracer_forwards_spans_to_the_profiler(tmp_path):
+    """A recording Tracer keeps its Chrome events and also annotates the
+    profiler with the same spans."""
+    import jax
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("iter", cat="engine") as sp:
+            with tr.span("stats.pull"):
+                pass
+            sp.set(it=2)
+        tr.complete("migration.drain", 0.0, 1.0)     # Chrome-only
+    finally:
+        jax.profiler.stop_trace()
+    xs = [e["name"] for e in tr.to_chrome()["traceEvents"]
+          if e["ph"] == "X"]
+    assert xs == ["stats.pull", "iter", "migration.drain"]
+    spans = _profiled_spans(tmp_path)
+    assert [n for n, *_ in spans] == ["iter", "stats.pull"]
+    assert spans[0][3]["it"] == 2
 
 
 # --------------------------------------------------------------------------
@@ -404,7 +472,7 @@ def test_audit_jsonl_roundtrip(tmp_path):
 def test_audit_disabled_by_default_no_overhead():
     pcfg = PlacementConfig(replan_every=2, warmup_iters=1, min_gain=0.0)
     mgr = PlacementManager.from_geometry(8, pcfg, 4, bytes_per_expert=7)
-    assert mgr.audit is None and mgr.tracer is NULL_TRACER
+    assert mgr.audit is None and mgr.tracer is DEFAULT_TRACER
     mgr.observe(_skew_stats([SKEW]))
     assert mgr.maybe_replan(2) is not None             # planning unaffected
 
@@ -532,10 +600,10 @@ def test_engine_trace_reconciles_migration_accounting(model, migrate_async,
         assert hidden_s > 0
     else:
         assert hidden_s == 0.0 and stall_s > 0
-    # the span vocabulary the ISSUE names is present
+    # the documented span vocabulary is present
     names = {e["name"] for e in obj["traceEvents"] if e.get("ph") == "X"}
-    assert {"iter", "admit", "migration.drain"} <= names
-    assert names & {"forward.chunk", "forward.decode", "forward.prefill"}
+    assert {"iter", "admit", "migrate", "migration.drain"} <= names
+    assert names & {"launch.chunk", "launch.decode", "launch.prefill"}
     assert any(e["name"] == "replan.placement"
                for e in obj["traceEvents"])
     assert any(e["name"] == "table.commit"
@@ -577,6 +645,119 @@ def test_engine_disabled_tracer_bitwise_parity(model):
     # apply wall time — nondeterministic run-to-run with or without a
     # tracer — so they are excluded from the parity check)
     assert base[1:] == traced[1:]
+
+
+#: The spans of the first step of ``_served``: three prompts complete in
+#: one chunk, then the three slots decode; every span is a child of iter.
+FIRST_STEP = ["admit", "chunk.inputs", "launch.chunk", "sample",
+              "stats.pull", "stats.observe", "retire", "decode.inputs",
+              "launch.decode", "sample", "stats.pull", "stats.observe",
+              "retire"]
+
+
+def _served(model, tracer=None):
+    """A tiny chunked engine on the virtual clock, run to the end: three
+    slots, six 12-token prompts, four new tokens each."""
+    from repro.serving.engine import Engine
+    from repro.workloads import IterationCostModel, VirtualClock
+    cfg, params = model
+    eng = Engine(cfg, params, ReaLBConfig(gate_gamma=4), max_slots=3,
+                 max_len=32, clock=VirtualClock(),
+                 cost_model=IterationCostModel(), tracer=tracer)
+    for r in _reqs(cfg, n=6, seed=1):
+        eng.submit(r)
+    steps = 0
+    while not eng.scheduler.idle:
+        eng.step()
+        steps += 1
+    return eng, steps
+
+
+def _children(spans, steps):
+    """Group (name, start, end, args) spans under their iter span; every
+    other span must lie inside exactly one iter."""
+    iters = [sp for sp in spans if sp[0] == "iter"]
+    assert len(iters) == steps
+    kids = {i: [] for i in range(len(iters))}
+    for sp in spans:
+        if sp[0] == "iter":
+            continue
+        inside = [i for i, it in enumerate(iters)
+                  if it[1] <= sp[1] and sp[2] <= it[2]]
+        assert len(inside) == 1, sp
+        kids[inside[0]].append(sp)
+    return iters, kids
+
+
+@pytest.fixture(scope="module")
+def profiled(model, tmp_path_factory):
+    """The default tracer's engine under a profiler trace."""
+    import jax
+    d = tmp_path_factory.mktemp("profile")
+    jax.profiler.start_trace(str(d))
+    try:
+        eng, steps = _served(model)
+    finally:
+        jax.profiler.stop_trace()
+    return eng, steps, _profiled_spans(d)
+
+
+@pytest.mark.slow
+def test_engine_spans_on_the_profiler_host_plane(profiled):
+    """Without a recording tracer the engine's spans reach the profiler's
+    host plane, one iter per step, the rest nested inside."""
+    eng, steps, spans = profiled
+    iters, kids = _children(spans, steps)
+    assert [sp[0] for sp in kids[0]] == FIRST_STEP
+    names = {sp[0] for sp in spans}
+    assert names == set(FIRST_STEP) | {"iter"}
+    # a decode-only step: no chunk spans
+    assert [sp[0] for sp in kids[1]] == ["admit", "retire"] + FIRST_STEP[7:]
+
+
+@pytest.mark.slow
+def test_engine_profiler_span_args(profiled):
+    """The iter span carries the step's rows; stats.pull counts its
+    device-to-host transfers (moe_stats and five scalars)."""
+    _, steps, spans = profiled
+    iters, kids = _children(spans, steps)
+    args = iters[0][3]
+    assert (args["it"], args["admitted"], args["chunk_rows"],
+            args["chunk_tokens"], args["decode_rows"]) == (1, 3, 3, 36, 3)
+    pulls = [sp[3] for sp in spans if sp[0] == "stats.pull"]
+    assert pulls and all(a["transfers"] == 6 for a in pulls)
+
+
+@pytest.mark.slow
+def test_engine_chrome_trace_span_vocabulary(model):
+    """A recording Tracer's Chrome JSON holds the same spans as the
+    profiler, nested the same way."""
+    tracer = Tracer()
+    eng, steps = _served(model, tracer)
+    events = validate_chrome_trace(tracer.to_chrome())
+    spans = sorted(((e["name"], e["ts"], e["ts"] + e["dur"],
+                     e.get("args", {})) for e in events if e["ph"] == "X"),
+                   key=lambda e: (e[1], -e[2]))
+    iters, kids = _children(spans, steps)
+    assert [sp[0] for sp in kids[0]] == FIRST_STEP
+    assert iters[0][3]["decode_rows"] == 3
+
+
+@pytest.mark.slow
+def test_request_stamps_on_the_engine_clock(profiled):
+    """Admission and per-token stamps: admitted before the first token,
+    one stamp per token, the first the first-token time; the chunk's and
+    the decode's sample of one step stamp apart."""
+    eng, _, _ = profiled
+    done = eng.scheduler.finished
+    assert len(done) == 6
+    for r in done:
+        assert r.arrival_time <= r.admit_time <= r.token_times[0]
+        assert len(r.token_times) == len(r.generated) == 4
+        assert r.token_times[0] == r.first_token_time
+        assert r.token_times == sorted(r.token_times)
+        assert r.token_times[1] > r.token_times[0]
+        assert r.finish_time >= r.token_times[-1]
 
 
 @pytest.mark.slow

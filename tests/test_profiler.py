@@ -1,8 +1,7 @@
 """Hot-loop profiler (repro.obs.ledger / repro.obs.profiler): FLOP/byte
 ledger math vs hand counts (BF16 + FP4 arms), costmodel formula pinning,
-instrumented-prefix bitwise parity with the fused MoE layer, disabled-
-profiler engine parity, drift/reconciliation under the virtual clock,
-cost-gate time_scale wiring, and profile_report exit codes."""
+disabled-profiler engine parity, drift/reconciliation under the virtual
+clock, cost-gate time_scale wiring, and profile_report exit codes."""
 import json
 
 import numpy as np
@@ -11,8 +10,8 @@ import pytest
 from repro.configs import (PlacementConfig, ReaLBConfig, get_config,
                            reduced)
 from repro.configs.hw import HBM_BW, PEAK_BF16, PEAK_INT8
-from repro.obs import (MOE_STAGES, NULL_PROFILER, PHASES, FlopByteLedger,
-                       MetricsRegistry, Profiler, time_moe_phases)
+from repro.obs import (NULL_PROFILER, PHASES, FlopByteLedger,
+                       MetricsRegistry, Profiler)
 from repro.obs.ledger import BYTES_BF16, BYTES_FP4, FIXED_US
 from repro.serving.telemetry import Telemetry
 
@@ -266,86 +265,6 @@ def test_cost_gate_time_scale_scales_layer_seconds(lcfg):
     assert cal.time_scale is None
     cal.time_scale = 2.0
     assert cal.layer_seconds(loads) == pytest.approx(2.0 * t1)
-
-
-# --------------------------------------------------------------------------
-# instrumented execution mode: prefix timings, bitwise ≡ fused
-# --------------------------------------------------------------------------
-@pytest.fixture(scope="module")
-def moe_setup():
-    import jax
-    cfg = reduced(get_config("olmoe-1b-7b"))
-    e = cfg.moe
-    ks = jax.random.split(jax.random.PRNGKey(1), 6)
-    D, E, F = cfg.d_model, e.num_experts, e.d_ff
-    p = {
-        "router": jax.random.normal(ks[0], (D, E)) * 0.2,
-        "w_gate": jax.random.normal(ks[1], (E, D, F)) / np.sqrt(D),
-        "w_up": jax.random.normal(ks[2], (E, D, F)) / np.sqrt(D),
-        "w_down": jax.random.normal(ks[3], (E, F, D)) / np.sqrt(F),
-    }
-    x = jax.random.normal(ks[4], (2, 16, D)) * 0.5
-    mod = jax.random.bernoulli(ks[5], 0.6, (2, 16))
-    return cfg, p, x, mod
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("mode", ["dispatch", "broadcast"])
-@pytest.mark.parametrize("backend", ["jnp", "interpret"])
-def test_instrumented_prefixes_bitwise_match_fused(moe_setup, mode, backend):
-    """The final stop_stage prefix IS the fused layer: y / m_state are
-    bitwise identical, and every stage gets a non-negative timing.  Runs
-    once on the jnp fallback and once with the Pallas grouped FP4 FFN /
-    quantize kernels wired in (interpret mode) — the stop_stage prefix
-    machinery must stay bitwise-transparent either way."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import ep_moe
-    from repro.kernels import ops as kops
-    cfg, p, x, mod = moe_setup
-    # virtual 4-rank EP group (m_state trailing dim), gate_gamma=1 opens
-    # the LB gate and m=0 drops the modality threshold so quantize_fp4
-    # really runs on the hot ranks
-    rcfg = ReaLBConfig(gate_gamma=1)
-    m = jnp.zeros((1, EP))
-    kops.set_ffn_backend(backend)
-    try:
-        seconds, out = time_moe_phases(p, x, cfg, rcfg, m, mode=mode,
-                                       modality=mod, repeats=1, warmup=1)
-        assert set(seconds) == set(MOE_STAGES[mode])
-        assert all(v >= 0.0 for v in seconds.values())
-        y, m2, aux = out
-
-        fused = jax.jit(lambda p_, x_, m_: ep_moe.ep_moe_forward(
-            p_, x_, cfg, rcfg, m_, mod, mode=mode))
-        y_ref, m_ref, aux_ref = fused(p, x, m)
-    finally:
-        kops.set_ffn_backend(None)
-    assert np.asarray(y).tobytes() == np.asarray(y_ref).tobytes()
-    assert np.asarray(m2).tobytes() == np.asarray(m_ref).tobytes()
-    assert set(aux) == set(aux_ref)
-    for k2 in aux:
-        np.testing.assert_array_equal(np.asarray(aux[k2]),
-                                      np.asarray(aux_ref[k2]))
-    assert float(aux["fp4_ranks"]) > 0    # the gate really opened
-
-
-def test_stop_stage_returns_prefix_boundaries(moe_setup):
-    """Early stops return raw boundary values (not the (y, m, aux)
-    triple) so each prefix keeps its phase outputs live."""
-    import jax.numpy as jnp
-
-    from repro.core import ep_moe
-    cfg, p, x, mod = moe_setup
-    rcfg = ReaLBConfig(gate_gamma=10 ** 9)
-    m = jnp.full((1, 1), 0.9)
-    out = ep_moe.ep_moe_forward(p, x, cfg, rcfg, m, mod, mode="dispatch",
-                                stop_stage="route")
-    assert isinstance(out, tuple) and len(out) == 5
-    full = ep_moe.ep_moe_forward(p, x, cfg, rcfg, m, mod, mode="dispatch",
-                                 stop_stage=None)
-    assert len(full) == 3 and full[0].shape == x.shape
 
 
 # --------------------------------------------------------------------------
