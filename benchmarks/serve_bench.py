@@ -82,7 +82,6 @@ from repro.workloads.profiles import WORKLOADS
 # ReaLBConfig overrides per ablation arm
 POLICIES = {
     "realb": {},
-    "realb-seq": {"overlap": False},     # serialise quantize after dispatch
     "off": {"enabled": False},           # never compress
 }
 

@@ -59,4 +59,4 @@ print(f"policy: IB_d={np.round(np.asarray(dec.ib_d),2)} "
       f"-> FP4 ranks={np.asarray(dec.use_fp4)} "
       f"(M_d -> {np.round(np.asarray(dec.m_new), 2)})")
 print("rank 0 exceeds IB>C with R_v>M_d ⇒ executes its experts in FP4; "
-      "its quantization is overlapped with the dispatch all-to-all.")
+      "its weights are quantized inside its expert compute.")

@@ -47,10 +47,12 @@ _CENSUS_PRIMS = ("psum", "all_to_all", "ppermute", "all_gather",
 #: default name-stack allowlist for widening converts: phases where a
 #: float widening is the algorithm (f32 softmax/logits in `route`, f32
 #: gate accumulation in `combine`, f32 norm statistics, attention
-#: softmax, aux losses).  Matched against the eqn's full name stack.
+#: softmax, aux losses, the f32 SiLU gate of the expert FFN, the f32
+#: scales of the FP4 weight and activation quantizers).  Matched against
+#: the eqn's full name stack.
 DEFAULT_WIDEN_ALLOWLIST: Tuple[str, ...] = (
     "route", "combine", "norm", "attention", "aux", "softmax", "rope",
-    "embed", "logits",
+    "embed", "logits", "swiglu", "quantize_fp4", "quantize_a4",
 )
 
 

@@ -261,7 +261,6 @@ class ReaLBConfig:
     md_min: float = 0.0
     gate_gamma: int = 2048        # Γ: global token threshold for LB gate
     adaptive: bool = True         # False -> ReaLB-m* fixed-threshold variants
-    overlap: bool = True          # False -> ReaLB-seq
     group_size: int = 16          # NVFP4 quant group
     wq_bits: int = 4
 

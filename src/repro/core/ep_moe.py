@@ -12,17 +12,20 @@ Two execution paths:
   ``all_to_all`` token exchange over the EP axis, local re-sort by expert,
   grouped GEMM via ``lax.ragged_dot`` (per-rank time scales with the true
   received load — straggler dynamics are preserved on TPU), ``all_to_all``
-  combine.  ReaLB's metadata collection (psum of routing counts) and the
-  conditional BF16→FP4 weight transformation have **no data dependency on
-  the dispatch all_to_all**, so XLA's latency-hiding scheduler overlaps
-  them with communication — the paper's pipeline orchestration (§4.3),
-  expressed structurally.  ``overlap=False`` (ReaLB-seq) inserts an
-  artificial dependency to serialise, for the ablation.
+  combine.  ReaLB's metadata collection (psum of routing counts) has **no
+  data dependency on the dispatch all_to_all**, so XLA's latency-hiding
+  scheduler overlaps it with communication.  The conditional BF16→FP4
+  weight transformation runs inside the expert compute's FP4 branch, so a
+  BF16 step (most steps) builds no FP4 weights; on a rank where FP4 fires
+  it follows the dispatch instead of hiding under it (the paper's §4.3
+  pipelining), the price of dropping a zero-weight pass from every other
+  step.
 
 * ``broadcast`` (decode, small token counts): tokens are replicated over
   the EP axis; each rank computes only its local experts' contributions and
   a ``psum`` combines.  This is the standard small-batch EP regime where
-  the paper's LB gate keeps ReaLB off.
+  the paper's LB gate keeps ReaLB off.  Its FP4 branch, too, quantizes
+  the weights itself.
 
 The per-rank precision decision is a *traced* ``lax.cond`` whose predicate
 is rank-local — SPMD HLO ``conditional``, each EP rank dynamically takes
@@ -331,7 +334,8 @@ def _grouped_ffn(xs, gs, w_gate, w_up, w_down, act):
     """xs [m,D] sorted by group; gs [G]; w_* [G,.,.] (contraction on dim 1)."""
     g = _rdot(xs, w_gate.astype(xs.dtype), gs)
     u = _rdot(xs, w_up.astype(xs.dtype), gs)
-    h = act(g.astype(F32)).astype(xs.dtype) * u
+    with jax.named_scope("swiglu"):
+        h = act(g.astype(F32)).astype(xs.dtype) * u
     return _rdot(h, w_down.astype(xs.dtype), gs)
 
 
@@ -350,7 +354,8 @@ def _grouped_ffn_fp4(xs, gs, wq: Dict[str, quant.QTensor],
         return kops.grouped_fp4_ffn(xs, gs, wq, group=rcfg.group_size,
                                     act=act)
     dq = {n: quant.dequantize_fp4(q, xs.dtype) for n, q in wq.items()}
-    xq = nvfp4.fake_quant_a4(xs, rcfg.group_size).astype(xs.dtype)
+    with jax.named_scope("quantize_a4"):
+        xq = nvfp4.fake_quant_a4(xs, rcfg.group_size).astype(xs.dtype)
     g = jax.lax.ragged_dot(xq, dq["w_gate"], gs, preferred_element_type=F32)
     u = jax.lax.ragged_dot(xq, dq["w_up"], gs, preferred_element_type=F32)
     # h stays f32 up to its a4: XLA may skip a bf16 rounding of h (excess
@@ -360,45 +365,29 @@ def _grouped_ffn_fp4(xs, gs, wq: Dict[str, quant.QTensor],
     return _rdot(hq, dq["w_down"], gs)
 
 
-def _quantize_experts(w: Dict[str, jax.Array], use_fp4: jax.Array,
-                      rcfg: ReaLBConfig,
-                      overlap_token: Optional[jax.Array]) -> Dict[str, Any]:
-    """③ on-the-fly BF16→FP4 transformation, conditional on the plan.
+def _pad_row(a: jax.Array) -> jax.Array:
+    """Append a copy of the first expert (the capacity pad slot)."""
+    return jnp.concatenate([a, a[:1]], axis=0)
 
-    Consumes only resident weights plus the routing-metadata predicate, so
-    the HLO has no dependency path from the dispatch all_to_all into these
-    ops — XLA overlaps them with communication.  ``overlap_token``
-    (ReaLB-seq ablation) injects a fake dependency on the a2a output to
-    serialise the transformation after dispatch.
-    """
 
-    def do_quant(ws):
-        out = {}
-        use_kernel = kops.ffn_backend() != "jnp"
-        for name, wt in ws.items():      # [G, K, N]: quantize along K
-            if overlap_token is not None:
-                wt = wt + overlap_token.astype(wt.dtype)
-            if use_kernel:
-                # Pallas quantize kernel — bitwise-identical to the jnp
-                # recipe, but streams the slab once.
-                out[name] = kops.quantize_experts_fp4(
-                    wt, group=rcfg.group_size)
-            else:
-                out[name] = quant.quantize_fp4(wt, rcfg.group_size)
-        return out
-
-    def no_quant(ws):
-        # zeros derived from the weights so the varying-manual-axes (VMA)
-        # type matches the quantizing branch under shard_map
-        out = {}
-        for name, wt in ws.items():
-            out[name] = quant.QTensor(
-                (wt[..., ::2, :] * 0).astype(jnp.uint8),
-                (wt[..., ::rcfg.group_size, :] * 0).astype(F32),
-                (wt.reshape(-1)[0] * 0 + 1).astype(F32))
-        return out
-
-    return jax.lax.cond(use_fp4, do_quant, no_quant, w)
+def _quantize_weights(ws: Dict[str, jax.Array], rcfg: ReaLBConfig,
+                      pad: int = 0) -> Dict[str, quant.QTensor]:
+    """③ BF16→FP4 transformation of the expert stacks ``[G, K, N]``
+    (quantized along K); ``pad`` (0 or 1) appends the pad slot."""
+    out = {}
+    use_kernel = kops.ffn_backend() != "jnp"
+    for name, wt in ws.items():
+        if use_kernel:
+            # Pallas quantize kernel — bitwise-identical to the jnp
+            # recipe, but streams the slab once and writes the pad slot
+            # itself, so the codes need no copy to gain it
+            out[name] = kops.quantize_experts_fp4(
+                wt, group=rcfg.group_size, pad=pad)
+        else:
+            q = quant.quantize_fp4(wt, rcfg.group_size)
+            out[name] = q if not pad else quant.QTensor(
+                _pad_row(q.packed), _pad_row(q.scales), q.global_scale)
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -456,12 +445,6 @@ def _moe_dispatch(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
     with jax.named_scope("weight_gather"):
         w = _gather_weights(p, comm)
 
-    # ③ conditional on-the-fly quantization (overlaps with a2a below) ------
-    wq = None
-    if not train and rcfg.overlap:
-        with jax.named_scope("quantize_fp4"):
-            wq = _quantize_experts(w, use_fp4_me, rcfg, None)
-
     # dispatch --------------------------------------------------------------
     # padding tokens are sorted to the back and never claim a capacity
     # slot, so they cannot crowd real tokens out of the per-rank cap (the
@@ -496,31 +479,36 @@ def _moe_dispatch(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
         recv = comm.a2a(send.reshape(ep, cap, d)).reshape(ep * cap, d)
         eid_recv = comm.a2a(eid_send.reshape(ep, cap)).reshape(ep * cap)
 
-    if not train and wq is None:   # ReaLB-seq: serialise T after dispatch
-        with jax.named_scope("quantize_fp4"):
-            token = (recv.sum() * 0.0).astype(F32)
-            wq = _quantize_experts(w, use_fp4_me, rcfg, token)
-
     # ④ balanced local expert compute ---------------------------------------
+    # (slot s_loc is the capacity pad slot: its rows take slot 0's weights)
+    def bf16_ffn(xs_, w_):
+        w_pad = {n: _pad_row(v) for n, v in w_.items()}
+        return _grouped_ffn(xs_, gs, w_pad["w_gate"], w_pad["w_up"],
+                            w_pad["w_down"], act)
+
+    def bf16_branch(o):
+        with jax.named_scope("expert_gemm"):
+            return bf16_ffn(o[0], o[1])
+
+    def fp4_branch(o):
+        # ③ the BF16→FP4 transformation runs only where FP4 fires
+        xs_, w_ = o
+        with jax.named_scope("quantize_fp4"):
+            wq_ = _quantize_weights(w_, rcfg, pad=1)
+        with jax.named_scope("expert_gemm"):
+            return _grouped_ffn_fp4(xs_, gs, wq_, rcfg, act)
+
     with jax.named_scope("expert_gemm"):
         order2 = jnp.argsort(eid_recv, stable=True)
         xs = jnp.take(recv, order2, axis=0)
         gs = jnp.bincount(eid_recv, length=s_loc + 1).astype(jnp.int32)
-        pad_row = lambda a: jnp.concatenate([a, a[:1]], axis=0)
-        w_pad = {n: pad_row(v) for n, v in w.items()}
         if train:
-            ys = _grouped_ffn(xs, gs, w_pad["w_gate"], w_pad["w_up"],
-                              w_pad["w_down"], act)
-        else:
-            wq_pad = {n: quant.QTensor(pad_row(v.packed), pad_row(v.scales),
-                                       v.global_scale)
-                      for n, v in wq.items()}
-            ys = jax.lax.cond(
-                use_fp4_me,
-                lambda o: _grouped_ffn_fp4(o[0], gs, o[2], rcfg, act),
-                lambda o: _grouped_ffn(o[0], gs, o[1]["w_gate"],
-                                       o[1]["w_up"], o[1]["w_down"], act),
-                (xs, w_pad, wq_pad))
+            ys = bf16_ffn(xs, w)
+    if not train:
+        # opened outside ``expert_gemm``: each branch names its own
+        # phases, so the quantize ops never count as expert GEMM time
+        ys = jax.lax.cond(use_fp4_me, fp4_branch, bf16_branch, (xs, w))
+    with jax.named_scope("expert_gemm"):
         y_buf = jnp.zeros_like(ys).at[order2].set(ys)
 
     with jax.named_scope("combine"):
@@ -589,30 +577,33 @@ def _moe_broadcast(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
     with jax.named_scope("weight_gather"):
         w = _gather_weights(p, comm)
 
-    with jax.named_scope("quantize_fp4"):
-        wq = _quantize_experts(w, use_fp4_me, rcfg, None)
-
     with jax.named_scope("expert_gemm"):
         pidx = flat_p.reshape(t, k)                            # [t,K] placed
         sel = (pidx // s_loc) == comm.my_rank                  # [t,K]
         local_gate = jnp.where(sel, gates, 0.0)
         leid = pidx % s_loc
 
-        def per_expert(x_all, wg, wu, wd):
-            g = jnp.einsum("td,edf->etf", x_all, wg.astype(x_all.dtype))
-            u = jnp.einsum("td,edf->etf", x_all, wu.astype(x_all.dtype))
-            h = act(g.astype(F32)).astype(x_all.dtype) * u
-            return jnp.einsum("etf,efd->etd", h, wd.astype(x_all.dtype))
+    def bf16_branch(o):
+        x_, w_ = o
+        with jax.named_scope("expert_gemm"):
+            g = jnp.einsum("td,edf->etf", x_, w_["w_gate"].astype(x_.dtype))
+            u = jnp.einsum("td,edf->etf", x_, w_["w_up"].astype(x_.dtype))
+            with jax.named_scope("swiglu"):
+                h = act(g.astype(F32)).astype(x_.dtype) * u
+            return jnp.einsum("etf,efd->etd", h,
+                              w_["w_down"].astype(x_.dtype))
 
-        def bf16_branch(o):
-            x_, w_, _ = o
-            return per_expert(x_, w_["w_gate"], w_["w_up"], w_["w_down"])
-
-        def fp4_branch(o):
+    def fp4_branch(o):
+        # ③ as in the dispatch path: a BF16 step builds no FP4 weights
+        x_, w_ = o
+        with jax.named_scope("quantize_fp4"):
+            wq_ = _quantize_weights(w_, rcfg)
+        with jax.named_scope("expert_gemm"):
             # same dynamic per-group a4 recipe as the grouped kernel, so
             # decode and prefill FP4 numerics agree across backends
-            x_, _, wq_ = o
-            xq = nvfp4.fake_quant_a4(x_, rcfg.group_size).astype(x_.dtype)
+            with jax.named_scope("quantize_a4"):
+                xq = nvfp4.fake_quant_a4(x_, rcfg.group_size) \
+                    .astype(x_.dtype)
             wd = {n: quant.dequantize_fp4(q, x_.dtype)
                   for n, q in wq_.items()}
             g = jnp.einsum("td,edf->etf", xq, wd["w_gate"],
@@ -623,8 +614,8 @@ def _moe_broadcast(x_t, mod_t, val_t, p, m_vec, cfg, rcfg, comm, act, rep,
                                      rcfg.group_size).astype(x_.dtype)
             return jnp.einsum("etf,efd->etd", hq, wd["w_down"])
 
-        y_e = jax.lax.cond(use_fp4_me, fp4_branch, bf16_branch,
-                           (x_t, w, wq))
+    # opened outside ``expert_gemm``, as in the dispatch path
+    y_e = jax.lax.cond(use_fp4_me, fp4_branch, bf16_branch, (x_t, w))
 
     with jax.named_scope("combine"):
         onehot = jax.nn.one_hot(leid, s_loc, dtype=y_e.dtype)  # [t,K,s_loc]

@@ -60,13 +60,14 @@ def _interpret(interpret: bool | None) -> bool:
     return (ffn_backend() != "pallas") if interpret is None else interpret
 
 
-def quantize_experts_fp4(w: jax.Array, *, group: int = 16,
+def quantize_experts_fp4(w: jax.Array, *, group: int = 16, pad: int = 0,
                          interpret: bool | None = None) -> QTensor:
     """Quantize a ``[G, K, N]`` expert weight stack along K via the Pallas
     kernel.  Bitwise-identical to ``quant.quantize_fp4`` (same global
-    scale over the whole stack, same per-group recipe)."""
+    scale over the whole stack, same per-group recipe); ``pad`` trailing
+    experts repeat the first ``pad``."""
     gscale = global_scale_for(w)
-    packed, scales = quantize_fp4_kernel(w, gscale, group=group,
+    packed, scales = quantize_fp4_kernel(w, gscale, group=group, pad=pad,
                                          interpret=_interpret(interpret))
     return QTensor(packed, scales, gscale)
 
@@ -78,7 +79,7 @@ def grouped_fp4_ffn(xs: jax.Array, gs: jax.Array,
     """Fused grouped FP4 SwiGLU FFN over slot-sorted tokens (see
     ``repro.kernels.grouped_fp4_ffn``).  ``wq`` holds ``w_gate``/``w_up``
     ``[G, D, F]`` and ``w_down`` ``[G, F, D]``, each quantized along its
-    contraction axis, exactly as ``_quantize_experts`` produces them."""
+    contraction axis, exactly as the EP layer's FP4 branch produces them."""
     qg, qu, qd = wq["w_gate"], wq["w_up"], wq["w_down"]
     gscales = jnp.stack([
         jnp.asarray(qg.global_scale, jnp.float32).reshape(()),

@@ -36,27 +36,30 @@ def _quantize_kernel(gscale_ref, w_ref, packed_ref, scales_ref, *,
     scales_ref[0] = scales
 
 
-@functools.partial(jax.jit, static_argnames=("group", "interpret"))
+@functools.partial(jax.jit, static_argnames=("group", "pad", "interpret"))
 def quantize_fp4_kernel(w: jax.Array, global_scale: jax.Array, *,
-                        group: int = GROUP, interpret: bool = False):
-    """w [G,K,N] bf16/f32 → (packed u8 [G,K/2,N], scales f32 [G,K/group,N])."""
+                        group: int = GROUP, pad: int = 0,
+                        interpret: bool = False):
+    """w [G,K,N] bf16/f32 → (packed u8 [G+pad,K/2,N], scales f32
+    [G+pad,K/group,N]); the ``pad`` trailing experts repeat the first
+    ``pad`` (the EP layer's capacity pad slot is one)."""
     g, k, n = w.shape
     assert k % (2 * group) == 0, (w.shape, group)
     block_n = LANES if n % LANES == 0 else n
     return pl.pallas_call(
         functools.partial(_quantize_kernel, group=group),
-        grid=(g, n // block_n),
+        grid=(g + pad, n // block_n),
         in_specs=[
             pl.BlockSpec((1, 1), lambda e, j: (0, 0)),
-            pl.BlockSpec((1, k, block_n), lambda e, j: (e, 0, j)),
+            pl.BlockSpec((1, k, block_n), lambda e, j: (e % g, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, k // 2, block_n), lambda e, j: (e, 0, j)),
             pl.BlockSpec((1, k // group, block_n), lambda e, j: (e, 0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((g, k // 2, n), jnp.uint8),
-            jax.ShapeDtypeStruct((g, k // group, n), jnp.float32),
+            jax.ShapeDtypeStruct((g + pad, k // 2, n), jnp.uint8),
+            jax.ShapeDtypeStruct((g + pad, k // group, n), jnp.float32),
         ],
         interpret=interpret,
         name="quantize_fp4",

@@ -119,7 +119,7 @@ def main() -> int:
     ap.add_argument("--meshes", default="single_pod,multi_pod")
     ap.add_argument("--tag", default="", help="record suffix (perf variants)")
     ap.add_argument("--realb", default="",
-                    help="comma k=v ReaLB overrides, e.g. overlap=False")
+                    help="comma k=v ReaLB overrides, e.g. gate_gamma=1024")
     ap.add_argument("--outdir", default="experiments/dryrun")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()

@@ -2,7 +2,7 @@
 
 A recorded stream pins the *exact* serving input — every token id,
 modality bit, arrival timestamp and generation length — so policy A/B
-runs (ReaLB vs. ReaLB-seq vs. off) see identical traffic, the same way
+runs (ReaLB vs. off) see identical traffic, the same way
 the iteration-level trace generator feeds identical randomness to every
 strategy simulator.
 

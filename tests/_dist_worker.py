@@ -942,6 +942,36 @@ def check_kernel_fp4_parity_under_ep():
     assert float(jnp.max(jnp.abs(y_mesh_k - y_off))) > 1e-6
 
 
+def check_fp4_transform_placement_under_ep():
+    """EP=4 on the (2,4) mesh: both paths quantize inside the expert
+    cond's FP4 branch, so the expert stacks feed one cond and no cond
+    yields FP4 codes (uint8)."""
+    from repro.analysis.jaxpr_audit import _walk
+    cfg, p, x, mod = _moe_setup()
+    e = cfg.moe
+    rcfg = ReaLBConfig(gate_gamma=1, capacity_c=0.5)
+    stacks = {(e.num_experts // 4, cfg.d_model, e.d_ff),
+              (e.num_experts // 4, e.d_ff, cfg.d_model)}
+    mesh = make_mesh((2, 4), ("data", "model"))
+    for mode, xs in (("dispatch", x), ("broadcast", x[:, :1])):
+        with use_mesh(mesh):
+            m = jnp.zeros(ep_moe.moe_state_shape(mesh, 4))
+            closed = jax.make_jaxpr(
+                lambda p_, x_, m_: ep_moe.ep_moe_forward(
+                    p_, x_, cfg, rcfg, m_, mod[:, :x_.shape[1]],
+                    mode=mode))(p, xs, m)
+        conds = []
+        _walk(closed.jaxpr, lambda eqn, mult: conds.append(eqn)
+              if eqn.primitive.name == "cond" else None)
+        fed = [c for c in conds
+               if any(getattr(v.aval, "shape", None) in stacks
+                      for v in c.invars)]
+        u8 = [c for c in conds
+              if any(v.aval.dtype == jnp.uint8 for v in c.outvars)]
+        assert len(fed) == 1, (mode, len(fed))
+        assert not u8, (mode, len(u8))
+
+
 CHECKS = {k[len("check_"):]: v for k, v in list(globals().items())
           if k.startswith("check_")}
 

@@ -32,6 +32,7 @@ CHECKS = [
     "elastic_kill_rejoin_under_ep",
     "kernel_fp4_parity_under_ep",
     "collective_census_reconciles",
+    "fp4_transform_placement_under_ep",
 ]
 
 

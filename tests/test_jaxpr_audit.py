@@ -142,3 +142,25 @@ def test_local_moe_path_audits_clean(moe):
     assert rep.ok, [v.format() for v in rep.violations]
     assert rep.census == {}, "local path must not emit collectives"
     assert rep.n_eqns > 50
+
+
+@pytest.mark.parametrize("mode", ["dispatch", "broadcast"])
+def test_fp4_branch_quantize_ops_are_audited(moe, mode):
+    """The FP4 branch quantizes under ``quantize_fp4``, one of the scopes
+    the widening check watches: a bf16 layer's f32 scale arithmetic shows
+    there, and it and the other by-design f32 phases of the expert FFN
+    are on the allowlist, so the bf16 layer audits clean."""
+    cfg, p, x, mod = moe
+    p = {n: v.astype(jnp.bfloat16) if n != "router" else v
+         for n, v in p.items()}
+    x = x.astype(jnp.bfloat16)
+    if mode == "broadcast":
+        x, mod = x[:, :1], mod[:, :1]
+    closed = jax.make_jaxpr(
+        lambda p_, x_: ep_moe.ep_moe_forward(
+            p_, x_, cfg, ReaLBConfig(gate_gamma=1e-6), jnp.zeros((1, 1)),
+            mod, mode=mode))(p, x)
+    rep = audit_jaxpr(closed)
+    assert any("quantize_fp4" in w["where"] and w["src"] == "bfloat16"
+               for w in rep.widenings), rep.widenings
+    assert rep.ok, [v.format() for v in rep.violations]

@@ -65,7 +65,7 @@ def test_quantize_kernel_odd_shapes(m, n, k):
 # fused grouped FP4 expert FFN vs the _grouped_ffn_fp4 jnp oracle
 # --------------------------------------------------------------------------
 def _quantized_experts(rng_key, n_groups, d, f, dtype=jnp.float32):
-    """QTensors in the exact layout _quantize_experts produces: gate/up
+    """QTensors in the exact layout the EP layer's FP4 branch produces: gate/up
     ``[G, D, F]`` quantized along D, down ``[G, F, D]`` along d_ff.
     Each weight is drawn output-axis-first and transposed."""
     keys = jax.random.split(rng_key, 3)
@@ -163,12 +163,14 @@ def test_grouped_ffn_kernel_block_m_invariance():
                                    rtol=1e-5, atol=1e-4)
 
 
-def test_quantize_experts_fp4_bitwise_matches_jnp():
+@pytest.mark.parametrize("pad", [0, 1])
+def test_quantize_experts_fp4_bitwise_matches_jnp(pad):
     """The grouped Pallas quantize path == quant.quantize_fp4 exactly
-    (same global scale over the stack, same per-group recipe)."""
+    (same global scale over the stack, same per-group recipe); with
+    ``pad`` the trailing experts repeat the first."""
     w = jax.random.normal(jax.random.PRNGKey(2), (5, 96, 48)) * 0.3
-    q_ref = quant.quantize_fp4(w)
-    q_k = ops.quantize_experts_fp4(w, interpret=True)
+    q_ref = quant.quantize_fp4(jnp.concatenate([w, w[:pad]], axis=0))
+    q_k = ops.quantize_experts_fp4(w, pad=pad, interpret=True)
     np.testing.assert_array_equal(np.asarray(q_k.packed),
                                   np.asarray(q_ref.packed))
     np.testing.assert_array_equal(np.asarray(q_k.scales),

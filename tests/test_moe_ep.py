@@ -1,6 +1,7 @@
 """EP MoE correctness: dispatch/broadcast paths vs a brute-force per-token
 dense reference; conservation, drops, ReaLB activation."""
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +9,8 @@ import numpy as np
 import pytest
 
 from repro.configs import ReaLBConfig, get_config, reduced
-from repro.core import ep_moe
+from repro.core import ep_moe, quant
+from repro.kernels import nvfp4
 
 
 @pytest.fixture(scope="module")
@@ -128,3 +130,157 @@ def test_aux_losses_finite_and_scaled(setup):
     lb = float(aux["lb_loss"])
     assert np.isfinite(lb) and 0.5 < lb < 64.0   # ~E for uniform routing
     assert np.isfinite(float(aux["z_loss"]))
+
+
+# --------------------------------------------------------------------------
+# the BF16->FP4 transform lives in the expert cond's FP4 branch (EP 1)
+# --------------------------------------------------------------------------
+FIRE = ReaLBConfig(gate_gamma=1, capacity_c=0.5)   # gate opens, rank hot
+
+
+def _expert_reference(cfg, rcfg, p, x, mod, m, mode, fp4):
+    """The layer on one device built from the separate pieces: routing and
+    policy, then ``quant.quantize_fp4`` + ``_grouped_ffn_fp4`` (dispatch)
+    or the dense FP4 einsum recipe (broadcast) when FP4 fires, else
+    ``_grouped_ffn`` / the dense BF16 einsums."""
+    e = cfg.moe
+    k, n_e, g = e.top_k, e.num_experts, rcfg.group_size
+    b, s, d = x.shape
+    t = b * s
+    act = jax.nn.silu
+    x_t = x.reshape(t, d)
+    gates, eidx, probs = ep_moe._route(p["router"], x_t, e)
+    flat_e = eidx.reshape(t * k)
+    w_val = jnp.ones((t * k,), jnp.float32)
+    w_vis = jnp.repeat(mod.reshape(t).astype(jnp.float32), k)
+    counts = jnp.bincount(flat_e, weights=w_val, length=n_e)
+    pol = m.shape[-1]
+    load_d = counts.reshape(pol, -1).sum(-1)
+    vis_d = jnp.bincount(flat_e, weights=w_vis, length=n_e) \
+        .reshape(pol, -1).sum(-1)
+    dec = ep_moe.realb_policy(load_d, vis_d, m.reshape(-1), rcfg)
+    assert bool(jnp.any(dec.use_fp4)) == fp4
+    w = {n: p[n] for n in ("w_gate", "w_up", "w_down")}
+    if mode == "dispatch":
+        # EP 1: the all_to_all is the identity; every assignment fits the
+        # layer's capacity buffer in order, the rest is the pad slot n_e
+        cap = max(8, -(-math.ceil(t * k * e.capacity_factor) // 8) * 8)
+        recv = jnp.zeros((cap, d), x.dtype).at[:t * k].set(
+            jnp.take(x_t, jnp.arange(t * k) // k, axis=0))
+        eid = jnp.full((cap,), n_e, jnp.int32).at[:t * k].set(flat_e)
+        order2 = jnp.argsort(eid, stable=True)
+        xs = jnp.take(recv, order2, axis=0)
+        gs = jnp.bincount(eid, length=n_e + 1).astype(jnp.int32)
+        pad = lambda a: jnp.concatenate([a, a[:1]], axis=0)
+        if fp4:
+            def fp4_ffn(xs, gs, w):
+                wq = {n: quant.quantize_fp4(v, g) for n, v in w.items()}
+                wq = {n: quant.QTensor(pad(q.packed), pad(q.scales),
+                                       q.global_scale)
+                      for n, q in wq.items()}
+                return ep_moe._grouped_ffn_fp4(xs, gs, wq, rcfg, act)
+            # one program, as the layer's cond branch is one: XLA fuses
+            # the fake-quant arithmetic alike only within one program
+            ys = jax.jit(fp4_ffn)(xs, gs, w)
+        else:
+            ys = ep_moe._grouped_ffn(xs, gs, pad(w["w_gate"]),
+                                     pad(w["w_up"]), pad(w["w_down"]), act)
+        y_buf = jnp.zeros_like(ys).at[order2].set(ys)
+        y = jnp.sum(y_buf[:t * k].reshape(t, k, d)
+                    * gates[..., None].astype(ys.dtype), axis=1)
+    else:
+        if fp4:
+            def fp4_ffn(x_t, w):
+                xq = nvfp4.fake_quant_a4(x_t, g).astype(x_t.dtype)
+                wd = {n: quant.dequantize_fp4(quant.quantize_fp4(v, g),
+                                              x_t.dtype)
+                      for n, v in w.items()}
+                gg = jnp.einsum("td,edf->etf", xq, wd["w_gate"],
+                                preferred_element_type=jnp.float32)
+                uu = jnp.einsum("td,edf->etf", xq, wd["w_up"],
+                                preferred_element_type=jnp.float32)
+                hq = nvfp4.fake_quant_a4(act(gg) * uu, g).astype(x_t.dtype)
+                return jnp.einsum("etf,efd->etd", hq, wd["w_down"])
+            y_e = jax.jit(fp4_ffn)(x_t, w)
+        else:
+            gg = jnp.einsum("td,edf->etf", x_t, w["w_gate"])
+            uu = jnp.einsum("td,edf->etf", x_t, w["w_up"])
+            hh = act(gg.astype(jnp.float32)).astype(x_t.dtype) * uu
+            y_e = jnp.einsum("etf,efd->etd", hh, w["w_down"])
+        onehot = jax.nn.one_hot(eidx, n_e, dtype=y_e.dtype)
+        weight_e = jnp.einsum("tk,tke->te", gates.astype(y_e.dtype), onehot)
+        y = jnp.einsum("te,etd->td", weight_e, y_e)
+    aux = ep_moe._aux_losses(probs, counts, jnp.sum(load_d) / k, e,
+                             lambda v: v)
+    aux.update(fp4_ranks=jnp.sum(dec.use_fp4.astype(jnp.float32)),
+               gate_open=dec.gate_open.astype(jnp.float32),
+               ib_global=dec.ib_global, load_d=load_d, vis_d=vis_d)
+    return y.astype(x.dtype).reshape(b, s, d), dec.m_new.reshape(m.shape), aux
+
+
+@pytest.mark.parametrize("fp4", [False, True], ids=["bf16", "fp4"])
+@pytest.mark.parametrize("mode", ["dispatch", "broadcast"])
+@pytest.mark.parametrize("vep", [1, 4])
+def test_expert_branches_match_reference_bitwise(setup, mode, fp4, vep):
+    """Output, AIMD state and policy aux equal the reference bit for bit,
+    in both branches of both paths, on a real and a virtual EP group."""
+    cfg, p, x, mod = setup
+    if mode == "broadcast":
+        x, mod = x[:, :1], mod[:, :1]
+    vis = jnp.ones_like(mod)
+    rcfg = FIRE if fp4 else ReaLBConfig(gate_gamma=10 ** 9)
+    m = jnp.zeros((1, vep))
+    y, m2, aux = ep_moe.ep_moe_forward(p, x, cfg, rcfg, m, vis, mode=mode)
+    y_r, m_r, aux_r = _expert_reference(cfg, rcfg, p, x, vis, m, mode, fp4)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_r))
+    np.testing.assert_array_equal(np.asarray(m2), np.asarray(m_r))
+    for name, ref in aux_r.items():
+        np.testing.assert_array_equal(np.asarray(aux[name]).reshape(-1),
+                                      np.asarray(ref).reshape(-1), name)
+    assert float(aux["drop_frac"]) == 0.0
+
+
+def _step_conds(closed):
+    """(cond eqn, how often it runs per step) for every cond in a step."""
+    from repro.analysis.jaxpr_audit import _walk
+    conds = []
+    _walk(closed.jaxpr, lambda eqn, mult: conds.append((eqn, mult))
+          if eqn.primitive.name == "cond" else None)
+    return conds
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "moonshot-v1-16b-a3b"])
+def test_one_device_step_builds_fp4_weights_only_in_the_fp4_branch(arch,
+                                                                   step):
+    """On one device no MoE cond hands FP4 codes (uint8) to another: the
+    expert stacks feed exactly one cond per MoE layer, the expert compute,
+    whose FP4 branch quantizes them itself."""
+    from repro.models import transformer as tf
+    cfg = reduced(get_config(arch))
+    e = cfg.moe
+    b, s, cache_len = 2, 8, 32
+    params = tf.abstract_model(cfg)
+    cache = tf.abstract_cache(cfg, b, cache_len)
+    m = jnp.zeros((1, 4))
+    i32 = jax.ShapeDtypeStruct((b,), jnp.int32)
+    if step == "decode":
+        batch = {"tokens": jax.ShapeDtypeStruct((b, 1), jnp.int32),
+                 "pos": i32}
+        fwd = tf.decode_forward
+    else:
+        batch = {"tokens": jax.ShapeDtypeStruct((b, s), jnp.int32),
+                 "start": i32, "chunk_len": i32}
+        fwd = tf.chunk_forward
+    closed = jax.make_jaxpr(
+        lambda p_, c_, b_: fwd(p_, cfg, FIRE, b_, c_, m))(params, cache, batch)
+    stacks = {(e.num_experts, cfg.d_model, e.d_ff),
+              (e.num_experts, e.d_ff, cfg.d_model)}
+    conds = _step_conds(closed)
+    fed = [(eqn, mult) for eqn, mult in conds
+           if any(getattr(v.aval, "shape", None) in stacks
+                  for v in eqn.invars)]
+    assert sum(mult for _, mult in fed) == cfg.ffn_kinds().count("moe")
+    for eqn, _ in conds:
+        assert not any(v.aval.dtype == jnp.uint8 for v in eqn.outvars), \
+            [str(v.aval) for v in eqn.outvars]

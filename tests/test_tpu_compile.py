@@ -40,11 +40,12 @@ def _compiled_text(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+@pytest.mark.parametrize("pad", [0, 1])
 @pytest.mark.parametrize("name,k,n", [("gate_up", D, F), ("down", F, D)])
-def test_quantize_kernel_compiles_for_v5e(one_chip, name, k, n):
+def test_quantize_kernel_compiles_for_v5e(one_chip, name, k, n, pad):
     spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
                                                   sharding=one_chip)
-    text = _compiled_text(lambda w, s: quantize_fp4_kernel(w, s),
+    text = _compiled_text(lambda w, s: quantize_fp4_kernel(w, s, pad=pad),
                           spec((G, k, n), jnp.bfloat16),
                           spec((), jnp.float32))
     assert "tpu_custom_call" in text
