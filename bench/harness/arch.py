@@ -1,60 +1,51 @@
-"""The sizes of a configuration file, read once, for the benchmark's own
-weight draw, reference and operation counts (no program import here)."""
+"""Finds the architecture a configuration runs, by name.
+
+A configuration file (``bench/configs/<config>.json``) may name
+``"arch": "<name>"``; without the key it runs ``gqa_moe``.  The module
+``bench/archs/<name>.py`` gives what the benchmark needs of that
+architecture, and nothing here names one:
+
+- ``sizes(conf)``: a hashable object of the sizes the benchmark reads,
+  with at least ``vocab``, ``d_model``, ``n_moe``, ``n_experts``,
+  ``top_k``, ``zipf_a``, ``embed_rms`` and ``id_noise_share`` (the
+  structured router draw, ``bench/harness/weights.py``);
+- ``model_config(name, sizes)``: the program's ``ModelConfig``;
+- ``layout(sizes)``: path -> (shape, dtype, std) of every parameter;
+- ``forward(params, sizes, tokens, fp4, rows, pad_to, mode)``: the plain
+  float32 reference (``fp4 [T, n_moe, ep]``: the EP ranks that ran a
+  position's MoE layer in FP4);
+- ``prefill_flops``, ``fp4_ffn_work``, ``quantize_work``: the counts the
+  per-layer readers use.
+"""
 from __future__ import annotations
 
-from dataclasses import dataclass
+import importlib.util
+import sys
+from pathlib import Path
+from types import ModuleType
+from typing import Dict, Tuple
+
+DEFAULT = "gqa_moe"
+ROOT = Path(__file__).resolve().parents[2]      # the checkout
+
+_LOADED: Dict[Path, ModuleType] = {}
 
 
-@dataclass(frozen=True)
-class Arch:
-    d_model: int
-    n_layers: int
-    n_dense: int               # leading dense-FFN layers
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int                  # dense FFN width (0 when no dense layer)
-    n_experts: int
-    top_k: int
-    d_expert: int
-    n_shared: int
-    vocab: int
-    rope_theta: float
-    norm_eps: float
-    capacity_factor: float
-    param_dtype: str
-    zipf_a: float
-    embed_rms: float
-    id_noise_share: float
-
-    @property
-    def n_moe(self) -> int:
-        return self.n_layers - self.n_dense
+def module(conf: dict, root: Path = ROOT) -> ModuleType:
+    """The architecture module that ``conf`` names."""
+    name = conf.get("arch", DEFAULT)
+    path = (root / "bench" / "archs" / f"{name}.py").resolve()
+    if path not in _LOADED:
+        key = f"bench_arch_{len(_LOADED)}_" + name.replace("-", "_")
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod          # dataclasses look their module up
+        spec.loader.exec_module(mod)
+        _LOADED[path] = mod
+    return _LOADED[path]
 
 
-def arch_of(conf: dict) -> Arch:
-    """Read a configuration file's published keys (HF names) plus its
-    ``assumed`` block."""
-    a = conf["assumed"]
-    d = int(conf["hidden_size"])
-    heads = int(conf["num_attention_heads"])
-    n_dense = int(conf.get("first_k_dense_replace", 0))
-    n_experts = int(conf.get("n_routed_experts", conf.get("num_experts", 0)))
-    router = a["structured_router"]
-    return Arch(
-        d_model=d, n_layers=int(conf["num_hidden_layers"]), n_dense=n_dense,
-        n_heads=heads,
-        n_kv_heads=int(conf.get("num_key_value_heads", heads)),
-        head_dim=int(a.get("head_dim", d // heads)),
-        d_ff=int(conf["intermediate_size"]) if n_dense else 0,
-        n_experts=n_experts, top_k=int(conf["num_experts_per_tok"]),
-        d_expert=int(conf.get("moe_intermediate_size",
-                              conf["intermediate_size"])),
-        n_shared=int(conf.get("n_shared_experts", 0)),
-        vocab=int(conf["vocab_size"]),
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        capacity_factor=float(a["capacity_factor"]),
-        param_dtype=a["param_dtype"],
-        zipf_a=float(router["zipf_a"]), embed_rms=float(router["embed_rms"]),
-        id_noise_share=float(router["id_noise_share"]))
+def of(conf: dict, root: Path = ROOT) -> Tuple[ModuleType, object]:
+    """(architecture module, sizes) of a configuration file."""
+    mod = module(conf, root)
+    return mod, mod.sizes(conf)

@@ -1,13 +1,18 @@
 """What a per-layer metric's reader gets: one run's window, step records,
-reduced trace and the chip's peaks.  A reader returns a number, or None
-where the run gave it nothing to read."""
+reduced trace, the chip's peaks and the architecture's counts.  A reader
+returns a number, or None where the run gave it nothing to read.
+
+Device-trace readers time the lowest device id; under the identity
+placement that is EP rank 0, so the counts held against its time are rank
+0's: its experts, its routed rows and its FP4 flag (on one chip, the
+whole layer)."""
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from types import ModuleType
 from typing import List, Optional
 
 from harness import policy
-from harness.arch import Arch
 from harness.serve import StepRecord, Window
 from harness.stats import Track
 from harness.trace import Device, Trace
@@ -15,14 +20,16 @@ from harness.trace import Device, Trace
 
 @dataclass
 class Run:
-    arch: Arch
+    arch: object                     # the architecture's sizes
+    counts: ModuleType               # bench/archs/<arch>.py: its counts
     chips: int
     peaks: dict
     window: Window
     due: List[Track]                 # requests due in the window
     steps: List[StepRecord]          # every step program call
     iter_stats: list                 # the engine's IterStats
-    virtual_ep: int
+    policy_ranks: int                # the policy's EP group (virtual: 1 chip)
+    ep: int = 1                      # physical EP ranks, one a chip
     trace: Optional[Trace] = None
     traced_steps: List[StepRecord] = field(default_factory=list)
 
@@ -31,19 +38,21 @@ class Run:
             return None
         return self.trace.devices[min(self.trace.devices)]
 
-    @staticmethod
-    def fired(step: StepRecord) -> List[bool]:
-        """Per MoE layer: did this step run it in FP4."""
-        return policy.layer_flags(step.aux["moe_stats"], step.m_in)[0]
+    def fired(self, step: StepRecord) -> List[bool]:
+        """Per MoE layer: did rank 0 run it in FP4."""
+        flags = policy.rank_flags(step.aux["moe_stats"], step.m_in, self.ep)
+        return [bool(f) for f in flags[:, 0]]
 
-    @staticmethod
-    def layer_rows(step: StepRecord) -> List[float]:
-        """Per MoE layer: routed assignments of real tokens."""
+    def layer_rows(self, step: StepRecord) -> List[float]:
+        """Per MoE layer: routed assignments of real tokens to rank 0's
+        experts."""
         ms = step.aux["moe_stats"]
-        return [float(ms[i, 0].sum()) for i in range(ms.shape[0])]
+        per = ms.shape[-1] // self.ep
+        return [float(ms[i, 0, :, :per].sum()) for i in range(ms.shape[0])]
 
-    @staticmethod
-    def layer_experts(step: StepRecord) -> List[int]:
-        """Per MoE layer: experts that received at least one token."""
+    def layer_experts(self, step: StepRecord) -> List[int]:
+        """Per MoE layer: rank 0's experts that received at least one
+        token."""
         es = step.aux["expert_stats"]
-        return [int((es[i, 0] > 0).sum()) for i in range(es.shape[0])]
+        per = es.shape[-1] // self.ep
+        return [int((es[i, 0, :per] > 0).sum()) for i in range(es.shape[0])]

@@ -1,24 +1,10 @@
-"""The configuration file as the program runs it: its ``ModelConfig``, its
-``ReaLBConfig`` and the engine the window drives."""
+"""The configuration as the program runs it: the parameter layout check,
+the mesh of a multi-chip cell and the engine the window drives."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
-
-from harness.arch import Arch
-
-
-def model_config(name: str, a: Arch):
-    from repro.configs.base import ModelConfig, MoEConfig
-    return ModelConfig(
-        name=name, family="moe", n_layers=a.n_layers, d_model=a.d_model,
-        n_heads=a.n_heads, n_kv_heads=a.n_kv_heads, head_dim=a.head_dim,
-        d_ff=a.d_ff, vocab_size=a.vocab,
-        moe=MoEConfig(num_experts=a.n_experts, top_k=a.top_k,
-                      d_ff=a.d_expert, n_shared_experts=a.n_shared,
-                      capacity_factor=a.capacity_factor),
-        n_dense_layers=a.n_dense, layer_pattern="attn", activation="swiglu",
-        rope_theta=a.rope_theta, norm_eps=a.norm_eps,
-        param_dtype=a.param_dtype)
 
 
 def check_layout(cfg, params) -> None:
@@ -30,6 +16,25 @@ def check_layout(cfg, params) -> None:
     if want != got:
         raise ValueError(f"drawn parameters do not match the program's "
                          f"layout:\n want {want}\n got {got}")
+
+
+def mesh_of(chips: int):
+    """The ``(1, chips)`` ``("data", "model")`` mesh of a multi-chip cell
+    (EP over every chip); None on one chip."""
+    if chips == 1:
+        return None
+    from repro.launch.mesh import make_mesh
+    return make_mesh((1, chips), ("data", "model"))
+
+
+def shardings(cfg) -> Optional[dict]:
+    """Each parameter's sharding under the active mesh, as the program lays
+    it out; None without a mesh."""
+    from repro.models import transformer as tf
+    from repro.models.common import current_mesh
+    if current_mesh() is None:
+        return None
+    return jax.tree.map(lambda s: s.sharding, tf.abstract_model(cfg))
 
 
 def make_engine(cfg, params, eng: dict, clock):

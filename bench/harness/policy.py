@@ -7,12 +7,13 @@ compressed when it is a hotspot, its vision share exceeds its threshold
 and the LB gate is open; the threshold falls by half while the global
 imbalance exceeds tau and rises by ``md_add`` otherwise, updated once per
 MoE layer.  On one chip the policy runs over a virtual EP group and any
-flagged virtual rank puts the whole layer in FP4.
+flagged virtual rank puts the whole layer in FP4; with one EP rank a chip
+each rank's flag is its own (``core/ep_moe.py``, ``use_fp4[my_rank]``).
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Tuple
+from typing import Iterator, Tuple
 
 import numpy as np
 
@@ -45,18 +46,29 @@ def step(load_d, vis_d, m, p: Policy = Policy()):
     return use, gate, float(ib_global), (m_new if gate else m)
 
 
-def layer_flags(moe_stats, m_in, p: Policy = Policy()
-                ) -> Tuple[List[bool], List[int], List[float]]:
-    """Per MoE layer of one step: FP4 on the whole layer (one chip: any
-    flagged virtual rank), flagged rank count, and ``ib_global``.
-    ``moe_stats`` is ``aux["moe_stats"]`` ``[layers, 2, groups, ep]``."""
+def layers(moe_stats, m_in, p: Policy = Policy()
+           ) -> Iterator[Tuple[np.ndarray, float]]:
+    """Per MoE layer of one step: the flagged ranks ``use [ranks]`` and
+    ``ib_global``.  ``moe_stats`` is ``aux["moe_stats"]``
+    ``[layers, 2, groups, ep]``."""
     ms = np.asarray(moe_stats, F)
     m = np.asarray(m_in, F).reshape(-1)
-    fired, ranks, ibs = [], [], []
     for layer in range(ms.shape[0]):
         use, _, ib, m = step(ms[layer, 0].reshape(-1),
                              ms[layer, 1].reshape(-1), m, p)
-        fired.append(bool(use.any()))
-        ranks.append(int(use.sum()))
-        ibs.append(ib)
-    return fired, ranks, ibs
+        yield use, ib
+
+
+def rank_flags(moe_stats, m_in, ep: int, p: Policy = Policy()
+               ) -> np.ndarray:
+    """``[layers, ep]``: which of the ``ep`` physical EP ranks ran each MoE
+    layer of one step in FP4.  On one chip (``ep`` 1) a flagged virtual
+    rank puts the whole layer in FP4; with a rank per chip, each rank
+    follows its own flag."""
+    use = np.stack([u for u, _ in layers(moe_stats, m_in, p)])
+    if use.shape[1] == ep:
+        return use
+    if ep == 1:
+        return use.any(1, keepdims=True)
+    raise ValueError(f"the policy flags {use.shape[1]} ranks; the program "
+                     f"runs {ep}")

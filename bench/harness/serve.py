@@ -7,8 +7,9 @@ sleeps only while the engine is idle.  A token is stamped after the
 ``step()`` that produced it (every step ends in the engine's sampling
 sync).  The :class:`Recorder` keeps, per step program call, what the
 reference and the readers need: the requests and positions it served,
-the AIMD state it started from, and its routing counters (device arrays,
-read after the window so the window gains no host sync).
+the AIMD state it started from, its routing counters and its dropped
+share (device arrays, read after the window so the window gains no host
+sync).
 """
 from __future__ import annotations
 
@@ -22,7 +23,7 @@ import numpy as np
 
 from harness.stats import Track
 
-AUX_KEYS = ("moe_stats", "expert_stats", "fp4_ranks")
+AUX_KEYS = ("moe_stats", "expert_stats", "fp4_ranks", "drop_frac")
 
 
 @dataclass

@@ -3,17 +3,20 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell (``BENCHMARK.json`` ``workloads``) names a configuration
-(``bench/configs/<name>.json``) and a traffic mix
-(``bench/traffic/<name>.json``).  The run draws the weights from the seed on
-the device, builds ``repro.serving.engine.Engine``, compiles every program
+(``bench/configs/<name>.json``, whose ``arch`` names its architecture
+module ``bench/archs/<arch>.py``), a traffic mix
+(``bench/traffic/<name>.json``) and its chips.  On more than one chip the
+run builds the ``(1, chips)`` ``("data", "model")`` mesh, EP over every
+chip.  The run draws the weights from the seed on the device (into their
+shards), builds ``repro.serving.engine.Engine``, compiles every program
 shape the mix uses (set-up), then offers the mix for ``--seconds`` on the
 wall clock (the window).  With ``--trace 0`` the last line of stdout holds
 the end-to-end metrics; with ``--trace 1`` the profiler records the tail of
 the window and the line holds the per-layer metrics
 (``bench/metrics/<name>.py``).  Either way the served tokens of a sample
-of finished requests are checked against the plain reference
-(``bench/harness/reference.py``), and the numbers compared are printed
-with their limits as the last lines of stderr.
+of finished requests are checked against the architecture's plain
+reference, and the numbers compared are printed with their limits as the
+last lines of stderr.
 
 Exits 2, printing no result, when JAX finds no TPU or fewer chips than the
 cell asks for.
@@ -40,8 +43,8 @@ sys.path[:0] = [str(BENCH), str(ROOT / "src")]
 
 import numpy as np  # noqa: E402
 
-from harness import check, spec, stats, traffic, weights  # noqa: E402
-from harness.arch import arch_of  # noqa: E402
+from harness import arch as archs  # noqa: E402
+from harness import check, model, spec, stats, traffic, weights  # noqa: E402
 from harness.context import Run  # noqa: E402
 from harness.flops import peaks  # noqa: E402
 from harness.serve import (Boundary, Recorder, closed_window,  # noqa: E402
@@ -134,10 +137,10 @@ def device_info(devices, chips: int) -> dict:
     return {"platform": d.platform, "kind": d.device_kind, "count": chips}
 
 
-def memory_peak(devices) -> int:
-    peaks_ = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-              for d in devices]
-    return int(max(peaks_))
+def memory_peaks(devices) -> list:
+    """Peak bytes in use on each device."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
 
 
 def end_to_end(w, due, setup_s: float, seconds: float) -> dict:
@@ -177,27 +180,39 @@ def engine_lines(eng, w, due) -> None:
 def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
              t_start: float, devices, root: Path = ROOT,
              engine_hook: Optional[Callable] = None,
-             control: bool = False) -> dict:
+             control: bool = False,
+             clock: Callable[[], float] = time.perf_counter) -> dict:
     """One run; returns the result line.  ``engine_hook(engine)`` runs on
     the built engine before set-up ends (tests plant faults with it);
     ``control`` also judges the fp8 control by the same verdict
-    (``control_correct``; bench/control.py)."""
-    import jax
+    (``control_correct``; bench/control.py); ``clock`` times the window."""
+    from repro.models.common import use_mesh
+    mesh = model.mesh_of(cell.chips)
+    with use_mesh(mesh):
+        return _run_cell(cell, seed, seconds, trace, t_start, devices, root,
+                         engine_hook, control, clock, mesh)
 
-    from harness.model import check_layout, make_engine, model_config
+
+def _run_cell(cell, seed, seconds, trace, t_start, devices, root,
+              engine_hook, control, win_clock, mesh) -> dict:
+    import jax
 
     clock = CompileClock()
     mix, eng_cfg = cell.traffic, cell.traffic["engine"]
-    arch = arch_of(cell.config)
-    cfg = model_config(cell.config_name, arch)
+    mod, arch = archs.of(cell.config, root)
+    cfg = mod.model_config(cell.config_name, arch)
     pk = peaks(devices[0].device_kind) if devices[0].platform == "tpu" \
         else None
+    shard = model.shardings(cfg)
+    if mesh is not None:
+        say(f"[setup] mesh {dict(mesh.shape)} over device ids "
+            f"{[d.id for d in mesh.devices.flat]}")
     t0 = time.perf_counter()
-    params = jax.block_until_ready(weights.draw(arch, seed))
-    check_layout(cfg, params)
+    params = jax.block_until_ready(weights.draw(mod, arch, seed, shard))
+    model.check_layout(cfg, params)
     say(f"[setup] weights drawn on the device in "
         f"{time.perf_counter() - t0} s")
-    eng = make_engine(cfg, params, eng_cfg, time.perf_counter)
+    eng = model.make_engine(cfg, params, eng_cfg, time.perf_counter)
     del params
     if engine_hook is not None:
         engine_hook(eng)
@@ -219,23 +234,24 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     try:
         if mix["kind"] == "closed":
             w = closed_window(eng, work, seconds, mix["think_s"],
-                              time.perf_counter, boundary)
+                              win_clock, boundary)
         else:
-            w = open_window(eng, work, seconds, time.perf_counter, boundary)
+            w = open_window(eng, work, seconds, win_clock, boundary)
     finally:
         if boundary is not None:
             boundary.stop()
     c_win = clock.snapshot()
     say(f"[window] compiles inside the window: "
         f"{c_win[1] - c_setup[1]} ({c_win[0] - c_setup[0]} s)")
-    mem = memory_peak(devices[:cell.chips])
+    per_chip = memory_peaks(devices[:cell.chips])
+    mem = max(per_chip)
     rec.to_host()
     due = stats.due_in(w.tracks, w.t0, w.close)
     engine_lines(eng, w, due)
     dev = device_info(devices, cell.chips)
     dev["memory_peak_bytes"] = mem
     say(f"[device] {dev['kind']} ({dev['platform']}), {cell.chips} chip(s); "
-        f"peak bytes in use {mem}")
+        f"peak bytes in use {mem} (by chip {per_chip})")
 
     result = {"correct": False, "attempted": len(due),
               "failed": sum(1 for t in due if t.failed)}
@@ -245,9 +261,10 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         trc = tr.load(str(files[-1]))
         say(f"[trace] {files[-1].stat().st_size} bytes: {tr.describe(trc)}")
         shutil.rmtree(trace_dir, ignore_errors=True)
-        run = Run(arch=arch, chips=cell.chips, peaks=pk, window=w, due=due,
-                  steps=rec.steps, iter_stats=eng.stats,
-                  virtual_ep=eng_cfg["virtual_ep"], trace=trc,
+        run = Run(arch=arch, counts=mod, chips=cell.chips, peaks=pk,
+                  window=w, due=due, steps=rec.steps, iter_stats=eng.stats,
+                  policy_ranks=int(eng.m_state.shape[-1]), ep=cell.chips,
+                  trace=trc,
                   traced_steps=[s for s in rec.steps
                                 if boundary.t_on <= s.t_call
                                 <= boundary.t_off])
@@ -273,21 +290,22 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
 
     # correctness: the program's state goes first, then the reference
     lim = cell.settings
+    drop = check.max_drop(rec.steps)
     picked = check.sample(w.requests, mix["check_sample"], seed)
     try:
-        seqs = check.sequences(picked, rec.steps, arch.n_moe)
+        seqs = check.sequences(picked, rec.steps, arch.n_moe, cell.chips)
     except ValueError as e:
         say(f"[check] {e}")
         seqs = []
     eng.params = eng.cache = None        # even if a cycle keeps eng alive
     del eng, rec
     gc.collect()
-    checked = {}
+    checked = {"drop_frac_max": {"value": drop, "limit": 0.0}}
     if seqs:
         from harness import reference
-        ref_params = weights.draw(arch, seed)
+        ref_params = weights.draw(mod, arch, seed, shard)
         t1 = time.perf_counter()
-        got = reference.check_sequences(ref_params, arch, seqs,
+        got = reference.check_sequences(mod.forward, ref_params, arch, seqs,
                                         eng_cfg["max_len"], control=control)
         del ref_params
         gap = float(got["gap"].mean())
@@ -307,9 +325,10 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
             checked["mean_logit_gap_fp8_control"] = {
                 "value": float(gc_.mean()), "limit": lim["mean_logit_gap"]}
             result["control_correct"] = check.verdict(
-                float(gc_.mean()), result["failed"], lim["mean_logit_gap"])
+                float(gc_.mean()), result["failed"], lim["mean_logit_gap"],
+                drop)
         result["correct"] = check.verdict(gap, result["failed"],
-                                          lim["mean_logit_gap"])
+                                          lim["mean_logit_gap"], drop)
     else:
         checked["served_tokens_checked"] = {"value": 0, "limit": 1}
     result["checked"] = checked

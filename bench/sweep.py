@@ -5,8 +5,9 @@ sustains without a growing backlog.
 
 One engine serves one window per rate (the cell's mix with ``mean_rate``
 replaced), draining between windows.  Per rate one JSON line: requests
-due and finished, the backlog at the close, and TTFT p90 over the
-window's first and second halves (a backlog that grows shows as a second
+due and finished, the backlog at the close, the largest share of routed
+assignments a step dropped, and TTFT p90 over the window's first and
+second halves (a backlog that grows shows as a second
 half far above the first).
 """
 from __future__ import annotations
@@ -20,8 +21,8 @@ import json  # noqa: E402
 import sys  # noqa: E402
 
 import run  # noqa: E402
-from harness import stats, traffic, weights  # noqa: E402
-from harness.arch import arch_of  # noqa: E402
+from harness import arch as archs  # noqa: E402
+from harness import model, stats, traffic, weights  # noqa: E402
 from harness.serve import open_window  # noqa: E402
 
 
@@ -31,22 +32,36 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--rates", type=float, nargs="+", required=True)
     ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--steady", action="store_true",
+                    help="Poisson arrivals at each rate, no bursts")
     args = ap.parse_args(argv)
     cell = run.spec.load_cell(args.workload)
     import jax
-    if jax.devices()[0].platform != "tpu":
+    devices = jax.devices()
+    if devices[0].platform != "tpu" or len(devices) < cell.chips:
         print("sweep: no TPU", file=sys.stderr)
         return 2
     run.enable_cache()
-    from harness.model import make_engine, model_config
-    arch = arch_of(cell.config)
-    cfg = model_config(cell.config_name, arch)
-    eng = make_engine(cfg, weights.draw(arch, args.seed),
-                      cell.traffic["engine"], time.perf_counter)
+    from repro.models.common import use_mesh
+    with use_mesh(model.mesh_of(cell.chips)):
+        sweep(cell, args)
+    return 0
+
+
+def sweep(cell, args) -> None:
+    mod, arch = archs.of(cell.config)
+    cfg = mod.model_config(cell.config_name, arch)
+    params = weights.draw(mod, arch, args.seed, model.shardings(cfg))
+    eng = model.make_engine(cfg, params, cell.traffic["engine"],
+                            time.perf_counter)
+    del params
     run.warm(eng, cell.traffic)
     for rate in args.rates:
         mix = dict(cell.traffic, mean_rate=rate)
+        if args.steady:
+            mix.update(calm_mult=1.0, burst_mult=1.0)
         specs = traffic.open_loop(mix, args.seed, args.seconds, arch.vocab)
+        n0 = len(eng.stats)
         w = open_window(eng, specs, args.seconds, time.perf_counter)
         mid = w.t0 + args.seconds / 2
         due = stats.due_in(w.tracks, w.t0, w.close)
@@ -61,9 +76,10 @@ def main(argv=None) -> int:
             "backlog": len(due) - fin, "ttft_p90_first_half_s": p90[0],
             "ttft_p90_second_half_s": p90[1],
             "tokens_per_s": stats.tokens_in(w.tracks, w.t0, w.close)
-            / args.seconds}), flush=True)
+            / args.seconds,
+            "max_drop_frac": max((st.drop_frac for st in eng.stats[n0:]),
+                                 default=0.0)}), flush=True)
         eng.run()                                  # drain
-    return 0
 
 
 if __name__ == "__main__":

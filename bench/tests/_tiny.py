@@ -42,11 +42,43 @@ def cell(root, limit: float, **over) -> spec.Cell:
                      end_to_end=[], per_layer=[])
 
 
+class StepClock:
+    """A window clock that moves one second per engine step, so a window
+    of ``n`` seconds runs ``n`` steps however busy the CPU is."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self) -> float:
+        return self.t
+
+    def hook(self, then=None):
+        """An engine hook that counts the steps, after ``then``."""
+        def hook(eng):
+            if then is not None:
+                then(eng)
+            step = eng.step
+
+            def counted():
+                out = step()
+                self.t += 1.0
+                return out
+            eng.step = counted
+        return hook
+
+
 def run(root, limit, seed=2 ** 33 + 5, seconds=2.0, mix_over=None,
-        **kw) -> dict:
+        steps=None, engine_hook=None, **kw) -> dict:
+    """One run of the tiny cell: ``seconds`` of wall clock, or ``steps``
+    engine steps."""
     import jax
     import run as bench_run
+    if steps is not None:
+        clock = StepClock()
+        kw.update(clock=clock)
+        engine_hook, seconds = clock.hook(engine_hook), float(steps)
     return bench_run.run_cell(cell(root, limit, **(mix_over or {})), seed,
                               seconds, False,
-                              time.perf_counter(), jax.devices(), root=root,
+                              time.perf_counter(), jax.devices(),
+                              root=_paths.ROOT, engine_hook=engine_hook,
                               **kw)

@@ -66,8 +66,12 @@ def _fp4_everywhere(eng):
                               "fp4_beyond_policy"])
 def test_fault_is_not_correct(tmp_path, fault):
     # FP4 flips about one served token in ten at these widths: twelve
-    # requests (some 80 tokens) leave none unflipped but by rare chance
-    res = _tiny.run(tmp_path, LIMIT, engine_hook=fault,
+    # requests (some 80 tokens) leave none unflipped but by rare chance.
+    # A decode that keeps its cache flips only a few tokens in a hundred,
+    # so which requests are checked decides its gap: the window runs a
+    # fixed number of engine steps, not the wall clock (at 150 steps its
+    # mean gap read 3.2e-3; 60 steps would have read 7.6e-4)
+    res = _tiny.run(tmp_path, LIMIT, engine_hook=fault, steps=150,
                     mix_over={"check_sample": 12})
     assert not res["correct"], res["checked"]
     assert res["checked"]["mean_logit_gap"]["value"] > LIMIT
@@ -78,4 +82,23 @@ def test_no_finished_request_is_not_correct(tmp_path):
                     mix_over={"output_len": {"min": 60, "max": 60},
                               "prompt_len": {"min": 8, "max": 8}})
     assert res["checked"]["served_tokens_checked"]["value"] == 0
+    assert not res["correct"]
+
+
+def _drops(eng):
+    """Every chunk step reports a share of its assignments dropped."""
+    chunk = eng._chunk
+
+    def dropping(*args):
+        logits, cache, m_state, aux = chunk(*args)
+        return logits, cache, m_state, dict(aux, drop_frac=aux["drop_frac"]
+                                            + 0.25)
+    eng._chunk = dropping
+
+
+def test_dropped_assignment_is_not_correct(tmp_path):
+    res = _tiny.run(tmp_path, LIMIT, engine_hook=_drops, steps=40)
+    got = res["checked"]
+    assert got["mean_logit_gap"]["value"] <= LIMIT
+    assert got["drop_frac_max"]["value"] == pytest.approx(0.25)
     assert not res["correct"]

@@ -8,9 +8,8 @@ import pytest
 
 import _paths  # noqa: F401
 import _tiny
-from harness import policy, reference, traffic, weights
-from harness.arch import arch_of
-from harness.model import check_layout, make_engine, model_config
+from harness import arch, policy, traffic, weights
+from harness.model import check_layout, make_engine
 from harness.serve import Recorder
 
 SEED = 2 ** 32 + 77
@@ -20,9 +19,9 @@ SEED = 2 ** 32 + 77
 def served():
     """One chunk step (8 vision-heavy prompts) and one decode step."""
     conf, mix = _tiny.config(), _tiny.mix()
-    a = arch_of(conf)
-    cfg = model_config("tiny", a)
-    params = weights.draw(a, SEED)
+    mod, a = arch.of(conf)
+    cfg = mod.model_config("tiny", a)
+    params = weights.draw(mod, a, SEED)
     check_layout(cfg, params)
     eng = make_engine(cfg, params, mix["engine"], time.perf_counter)
     rec = Recorder(eng)
@@ -33,19 +32,19 @@ def served():
                            max_new_tokens=s.max_new, arrival_time=0.0))
     eng.step()
     rec.to_host()
-    return a, params, specs, eng, rec
+    return mod, a, params, specs, eng, rec
 
 
 def test_program_and_reference_route_alike(served):
-    a, params, specs, _, rec = served
+    mod, a, params, specs, _, rec = served
     chunk = rec.steps[0]
     assert chunk.kind == "chunk" and len(chunk.rows) == len(specs)
     want = np.zeros((a.n_moe, a.n_experts))
     for s in specs:
         picks = []
-        reference.forward(params, a, s.tokens,
-                          np.zeros((len(s.tokens), a.n_moe), bool),
-                          np.array([len(s.tokens) - 1]), 64, picks=picks)
+        mod.forward(params, a, s.tokens,
+                    np.zeros((len(s.tokens), a.n_moe, 1), bool),
+                    np.array([len(s.tokens) - 1]), 64, picks=picks)
         for layer, idx in enumerate(picks):
             want[layer] += np.bincount(idx.reshape(-1), minlength=a.n_experts)
     got = chunk.aux["expert_stats"][:, 0, :]
@@ -55,13 +54,14 @@ def test_program_and_reference_route_alike(served):
 
 
 def test_vision_burst_fires_fp4_under_default_policy(served):
-    a, _, _, eng, rec = served
+    _, a, _, _, eng, rec = served
     first = eng.stats[0]
     assert first.phase == "prefill" and first.gate_open > 0
     assert first.fp4_ranks > 0
-    fired, ranks, ibs = policy.layer_flags(rec.steps[0].aux["moe_stats"],
-                                           rec.steps[0].m_in)
-    assert any(fired) and max(ibs) > policy.Policy().tau
+    uses, ibs = zip(*policy.layers(rec.steps[0].aux["moe_stats"],
+                                   rec.steps[0].m_in))
+    ranks = [int(u.sum()) for u in uses]
+    assert any(ranks) and max(ibs) > policy.Policy().tau
     assert sum(ranks) / a.n_moe == pytest.approx(first.fp4_ranks)
     assert sum(ranks) == pytest.approx(float(rec.steps[0].aux["fp4_ranks"]))
     decode = [s for s in eng.stats if s.phase == "decode"]
@@ -69,7 +69,7 @@ def test_vision_burst_fires_fp4_under_default_policy(served):
 
 
 def test_zipf_order_puts_hot_vision_experts_on_rank_zero():
-    a = arch_of(_tiny.config())
+    _, a = arch.of(_tiny.config())
     import jax
     member = np.asarray(weights.expert_sets(a, jax.random.PRNGKey(3)))
     assert np.all(member.sum(1) == a.top_k)

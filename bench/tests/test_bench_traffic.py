@@ -78,13 +78,16 @@ def test_open_loop_arrivals_follow_the_cumulative_rate():
 
 def _fits(ss):
     """Does schedule seed ``ss``'s 51 s draw offer each vision-burst
-    cell's mean rate within 5%, with 1.5/7.5 of it in bursts within 10%?"""
+    cell's mean rate within 5%, with 1.5/7.5 of it in bursts within 10%
+    and no burst longer than 3 x the mean burst dwell?"""
     for cell in ("moonlight-5l.vision-burst", "olmoe-8l.vision-burst"):
         m = dict(spec.load_cell(cell).traffic, schedule_seed=ss)
         n = len(traffic.schedule(m, 51.0)[0])
         e = np.minimum(traffic.phase_edges(m, 51.0), 51.0)
+        bursts = np.diff(e)[1::2]
         if abs(n / (m["mean_rate"] * 51.0) - 1) > 0.05 \
-                or abs(np.diff(e)[1::2].sum() / (51.0 * 1.5 / 7.5) - 1) > 0.1:
+                or abs(bursts.sum() / (51.0 * 1.5 / 7.5) - 1) > 0.1 \
+                or bursts.max() > 3 * m["burst_s"]:
             return False
     return True
 
